@@ -277,6 +277,7 @@ import numpy as np, jax
 from repro.core import ResilienceConfig, TCQService
 from repro.core.faultinject import FaultPlan, FaultyStep
 from repro.graphs import powerlaw_temporal
+from repro.launch.mesh import make_mesh
 
 cfg = json.loads(sys.argv[1])
 g = powerlaw_temporal(cfg["V"], cfg["E"], cfg["span"], seed=9)
@@ -295,7 +296,7 @@ def digest(tickets):
             for t in sorted(tickets, key=lambda t: t.id)]
 
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))   # lane-only: kernel rung up
+mesh = make_mesh((8, 1), ("data", "model"))   # lane-only: kernel rung up
 
 
 def drain(wrapper):

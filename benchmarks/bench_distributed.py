@@ -54,6 +54,7 @@ import json, time
 import numpy as np, jax
 from repro.core import TCQService
 from repro.graphs import powerlaw_temporal
+from repro.launch.mesh import make_mesh
 
 cfg = json.loads(sys.argv[1])
 g = powerlaw_temporal(cfg["V"], cfg["E"], cfg["span"], seed=9)
@@ -92,7 +93,7 @@ def digest(tickets):
 
 entries = [("single", None)]
 for L, M in cfg["shapes"]:
-    entries.append((f"{L}x{M}", jax.make_mesh((L, M), ("data", "model"))))
+    entries.append((f"{L}x{M}", make_mesh((L, M), ("data", "model"))))
 
 svcs, digests = {}, {}
 for name, mesh in entries:                 # warm round: compiles + digest

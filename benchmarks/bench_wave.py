@@ -83,7 +83,6 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
     v = g.num_vertices
     e = int(tel.t.shape[0])
     p = int(tel.pair_u.shape[0])
-    hp = int(tel.hp_src.shape[0])
     sp, sv = make_segsum_fns(g, use_kernel=False)
     fused = make_wave_step_fn(tel, v, use_kernel=True)
     comp = make_wave_step_fn(tel, v, use_kernel=False,
@@ -138,10 +137,9 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
     # lowering (edge activity + its transposed f32 segsum operand)
     we_census = hc.shape_census((wave, e)) + hc.shape_census((e, wave))
 
-    w_tile = getattr(fused, "w_tile", 8)
-    fc = fused_step_cost(e, p, hp, v, wave=wave, w_tile=w_tile, iters=iters)
+    fc = fused_step_cost(*fused.local_counts, wave=wave, iters=iters)
     # structural [W, E] check on the fused side: the kernel's only HBM
-    # operands are the [1, E_pad] tables and the [W_pad, V32] lane slab
+    # operands are the 1-D index tables and the [V_loc, W_pad] lane slab
     fused_we = sum(1 for s in getattr(fused, "operand_shapes", [])
                    if len(s) == 2 and set(s) == {wave, e} and e != wave)
     if fc["bytes_per_iter_hbm"] > 0:

@@ -8,7 +8,7 @@ the roofline delta vs a named baseline record.
 ``--wave-step`` instead audits the fused wave-peel kernel lowering: it
 lowers the unfused XLA peel chain, censuses its [W, E] HBM
 materializations, and ASSERTS the fused lowering eliminates them (its
-only HBM operands are the [1, E] tables and the [W, V] lane slab;
+only HBM operands are the 1-D index tables and the [V_loc, W] lane slab;
 per-iteration HBM bytes are zero by construction).
 
     PYTHONPATH=src python -m benchmarks.perf_lower --wave-step

@@ -406,14 +406,12 @@ def build_wave_step(mesh, *, num_vertices: int, combine: str = "rs_ag",
     lane_axes = dp if len(dp) > 1 else dp[0]
     lane = PS(lane_axes)
     alive_spec = PS(lane_axes, None)
-    from jax.experimental.shard_map import shard_map
-
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec, edge_spec, edge_spec,
                   edge_spec, alive_spec, lane, lane, PS(), PS()),
         out_specs=(alive_spec, lane, lane, lane, PS()),
-        check_rep=False)
+        check_vma=False)
     return smapped
 
 
@@ -459,8 +457,6 @@ def _sharded_step_jit(mesh, v_pad: int, p_cap: int, combine: str,
     """jit(shard_map) for the per-lane-vector sharded step.  Cached per
     (mesh, capacities, combine): jit itself re-specializes per edge-cap
     bucket, so one entry serves every window in a capacity class."""
-    from jax.experimental.shard_map import shard_map
-
     L, m = mesh_shard_counts(mesh)
     assert v_pad % max(1, m) == 0
     axes = _all_axes(mesh)
@@ -521,13 +517,13 @@ def _sharded_step_jit(mesh, v_pad: int, p_cap: int, combine: str,
         return StepResult(alive, _pack_u32(alive, v_pad), lo, hi,
                           n_edges, iters)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec, edge_spec, edge_spec,
                   edge_spec, alive_spec, lane, lane, lane, lane),
         out_specs=StepResult(alive_spec, PS(lane_axes, None), lane, lane,
                              lane, PS()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(smapped, donate_argnums=(6,) if donate else ())
 
 
@@ -569,7 +565,6 @@ def make_sharded_step_fn(mesh, arrays, *, num_vertices: int, p_cap: int,
 
 
 def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
-                             w_tile: int = 8,
                              interpret: Optional[bool] = None,
                              vmem_budget_bytes: Optional[int] = None):
     """Fused Pallas peel-to-fixpoint kernel as the per-shard local step.
@@ -586,13 +581,12 @@ def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
     L, m = mesh_shard_counts(mesh)
     if m != 1:
         return None
-    from jax.experimental.shard_map import shard_map
     from repro.kernels.wave_peel.ops import (DEFAULT_VMEM_BUDGET,
                                              make_fused_wave_step)
 
     budget = (DEFAULT_VMEM_BUDGET if vmem_budget_bytes is None
               else int(vmem_budget_bytes))
-    fused = make_fused_wave_step(tel, num_vertices, w_tile=w_tile,
+    fused = make_fused_wave_step(tel, num_vertices,
                                  interpret=interpret, donate=False,
                                  vmem_budget_bytes=budget)
     if fused is None:
@@ -607,12 +601,12 @@ def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
         res = fused(alive, ts, te, k, h)     # inlines: kernel per shard
         return res._replace(iters=lax.pmax(res.iters, axes))
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(alive_spec, lane, lane, lane, lane),
         out_specs=StepResult(alive_spec, PS(lane_axes, None), lane, lane,
                              lane, PS()),
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(smapped)
 
     def step(alive, ts, te, k, h):
@@ -622,6 +616,7 @@ def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
             for x in (ts, te, k, h)]
         return jitted(alive, *lanes)
 
+    step.jitted = jitted         # the shard_map program (compile checks)
     step.backend = "pallas"
     step.interpret = bool(getattr(fused, "interpret", False))
     step.combine = "none"
@@ -649,7 +644,7 @@ class ShardedDegradationLadder(DegradationLadder):
 
     def __init__(self, mesh, arrays, tel, num_vertices: int, *,
                  p_cap: int, combine: str = "psum",
-                 use_kernel: bool = False, w_tile: int = 8,
+                 use_kernel: bool = False,
                  config: Optional[ResilienceConfig] = None):
         # rebuild DegradationLadder.__init__'s state by hand: the rungs
         # here are sharded lowerings, not the single-device ones
@@ -670,8 +665,7 @@ class ShardedDegradationLadder(DegradationLadder):
             else:
                 try:
                     fused = make_sharded_kernel_step(
-                        mesh, tel, num_vertices, w_tile=w_tile,
-                        interpret=interpret,
+                        mesh, tel, num_vertices, interpret=interpret,
                         vmem_budget_bytes=self.config.vmem_budget_bytes)
                     if fused is None:
                         self._log("pallas", "vmem_budget", "")

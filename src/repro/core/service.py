@@ -591,6 +591,7 @@ class TCQService:
             "cache_hits": sum(tk.result.stats.cells_cached
                               for tk in members),
             "backend": getattr(wt.step_fn, "backend", "?"),
+            "interpret": bool(getattr(wt.step_fn, "interpret", False)),
             "wall_s": done_s - t0,
         })
         if pool_stats.shard_occupancy is not None:

@@ -392,7 +392,6 @@ class DegradationLadder:
                  seg_pair=None, seg_vert=None,
                  use_kernel: bool = False,
                  interpret: Optional[bool] = None,
-                 w_tile: int = 8,
                  config: Optional[ResilienceConfig] = None):
         self.config = config or ResilienceConfig()
         self.events = []            # [{rung, reason, detail, call}]
@@ -411,7 +410,6 @@ class DegradationLadder:
                       else int(self.config.vmem_budget_bytes))
             try:
                 fused = make_fused_wave_step(tel, num_vertices,
-                                             w_tile=w_tile,
                                              interpret=interpret,
                                              donate=False,
                                              vmem_budget_bytes=budget)
@@ -498,7 +496,7 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
                       seg_pair=None, seg_vert=None,
                       use_kernel: Optional[bool] = None,
                       interpret: Optional[bool] = None,
-                      w_tile: int = 8, donate: bool = False,
+                      donate: bool = False,
                       vmem_budget_bytes: Optional[int] = None,
                       resilience: Optional[ResilienceConfig] = None):
     """Build the device step for one TEL: ``step(alive, ts, te, k, h) ->
@@ -536,15 +534,14 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
                 resilience, vmem_budget_bytes=int(vmem_budget_bytes))
         return DegradationLadder(tel, num_vertices, seg_pair=seg_pair,
                                  seg_vert=seg_vert, use_kernel=use_kernel,
-                                 interpret=interpret, w_tile=w_tile,
-                                 config=resilience)
+                                 interpret=interpret, config=resilience)
     if use_kernel:
         from repro.kernels.wave_peel.ops import (DEFAULT_VMEM_BUDGET,
                                                  make_fused_wave_step)
 
         budget = (DEFAULT_VMEM_BUDGET if vmem_budget_bytes is None
                   else int(vmem_budget_bytes))
-        fused = make_fused_wave_step(tel, num_vertices, w_tile=w_tile,
+        fused = make_fused_wave_step(tel, num_vertices,
                                      interpret=interpret, donate=donate,
                                      vmem_budget_bytes=budget)
         if fused is not None:

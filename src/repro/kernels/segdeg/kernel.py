@@ -27,11 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific grid spec (scalar prefetch); interpret mode also uses it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(in_lo_ref, in_hi_ref, seg_ref, val_ref, out_ref, *,

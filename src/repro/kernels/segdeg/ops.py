@@ -17,10 +17,9 @@ from repro.kernels.segdeg.ref import banded_segsum_ref
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """True when JAX's default backend is a TPU.  Backend errors
+    propagate: a broken accelerator must not pass for "not a TPU"."""
+    return jax.default_backend() == "tpu"
 
 
 def make_banded_segsum(seg_ids_host, num_segments: int, *, k_cap: int = 16,

@@ -4,34 +4,36 @@ The XLA composite (`core/wave.py`'s ``peel_to_fixpoint`` chain) runs the
 fixpoint loop at HBM bandwidth: every iteration re-materializes the
 [W, E] edge-activity mask, the [P, W] pair counts and the [2P, W] pair
 contributions as separate fusion outputs.  This kernel runs the *entire*
-fixpoint loop per W-tile with every intermediate resident in VMEM:
+fixpoint loop per lane tile with every intermediate resident in VMEM.
 
-  grid = (W_tiles,)     one program owns a w_tile x V slab of lane state
+Layout (what Mosaic can lower: no vector gathers, no cumsum, no bool
+carries).  Query lanes ride the 128-wide lane axis and the window's
+*local* vertices / pairs ride the sublane axis, so every gather or
+scatter is a dynamic single-row load or store driven by a scalar index
+read from SMEM:
 
-  * per-lane (ts, te, k, h) ride in SMEM via scalar prefetch, so a
-    mixed-threshold multi-tenant wave shares one launch;
-  * window masking, edge activity, the banded pair-count, the
-    h-threshold, the vertex-degree accumulation and the k-survivor test
-    are one loop body — nothing crosses HBM between iterations;
-  * both segment reductions exploit the ArrayTEL canonical sort: a
-    sorted-segment sum is a *prefix-sum range difference*, so an int32
-    cumsum along the edge axis plus two boundary gathers (host-derived
-    ``segment_bounds`` tables, also prefetched) replaces the scatter /
-    one-hot matmul entirely;
-  * on the final iteration the kernel emits TTI lo/hi, per-lane live
-    edge counts and the uint32 bitmask pack directly, so the step's
-    HBM traffic is the TEL (read once per W-tile), the alive slab
-    (read + written once) and the packed/scalar outputs — independent
-    of the iteration count.
+  grid = (W_pad / 128,)   one program owns a [V_loc, 128] lane slab
 
-Segment sums here count *booleans*, so int32 prefix sums are exact and
-the kernel is bit-identical to the f32 composite (small integers are
-exact in f32).  Per-tile fixpoint iteration counts can only be <= the
-composite's global count, and a converged lane is invariant under extra
-iterations, so max-over-tiles equals the composite's ``iters`` exactly
-(asserted by the seeded fuzz gate in tests/test_kernels.py).
+  * the wrapper (``ops.make_fused_wave_step``) compacts the window once
+    on the host: live edges grouped by pair (the canonical sort), the
+    window's local pairs, and their endpoints relabelled 0..V_loc-1;
+    the index tables ride in SMEM;
+  * prologue: for every local pair, count its edges inside each lane's
+    window and store ``ok[p] = count >= h`` (one row per pair);
+  * fixpoint: a pair is active iff both endpoints are alive and
+    ``ok[p]`` — so the degree of an alive vertex v is the sum over its
+    half-pairs (v, o, p) of ``ok[p] * cur[o]``.  Degrees are computed
+    Jacobi-style from the previous iteration's mask, then one dense pass
+    applies ``cur & (deg >= k)`` and reports whether any lane changed;
+  * epilogue: edge activity of the fixpoint (window & both endpoints
+    alive) gives the per-lane edge count and TTI lo/hi.
 
-Validated on CPU with interpret=True against the XLA composite.
+Everything counts 0/1 values in int32, so the kernel is bit-identical to
+the f32 composite (small integers are exact in f32).  The per-tile
+iteration count is the composite's count restricted to that tile's lanes;
+a converged lane is invariant under extra iterations, so max-over-tiles
+equals the composite's ``iters`` exactly (seeded fuzz gate in
+tests/test_kernels.py).
 """
 
 from __future__ import annotations
@@ -40,147 +42,115 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU grid spec (scalar prefetch); interpret mode also uses it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+LANES = 128          # query lanes per grid program (the vreg lane width)
+STAT_ROWS = 8        # stats block rows: n_edges, tti_lo, tti_hi, iters, pad
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 _I32_MIN = jnp.iinfo(jnp.int32).min
 
 
-def segment_bounds(seg_ids_host, num_segments: int):
-    """Host-side band table for a *sorted* segment-id array: for segment
-    s, its rows are exactly ``[starts[s], ends[s])``.  Sentinel ids >=
-    ``num_segments`` (capacity padding) sort past every real segment and
-    fall outside every range."""
-    segs = np.asarray(seg_ids_host)
-    idx = np.arange(num_segments, dtype=np.int64)
-    starts = np.searchsorted(segs, idx, side="left").astype(np.int32)
-    ends = np.searchsorted(segs, idx, side="right").astype(np.int32)
-    return starts, ends
+def _kernel(t_ref, pend_ref, pu_ref, pv_ref, vend_ref, hpo_ref, hpp_ref,
+            prm_ref, alive_ref, cur_ref, st_ref, ok_ref, deg_ref,
+            *, n_pairs: int, n_verts: int):
+    ts, te = prm_ref[0:1, :], prm_ref[1:2, :]
+    kk, hh = prm_ref[2:3, :], prm_ref[3:4, :]
+    zero = jnp.zeros((1, LANES), jnp.int32)
 
+    def in_window(tv):
+        return (tv >= ts) & (tv <= te)
 
-def _banded_count(x, lo, hi):
-    """x: [w, N] int32; lo/hi: [S] row ranges (sorted segments).
-    Returns [w, S] int32 per-segment sums via prefix-sum differences."""
-    cum = jnp.cumsum(x, axis=1)
-    upper = jnp.take(cum, jnp.maximum(hi - 1, 0), axis=1)
-    lower = jnp.take(cum, jnp.maximum(lo - 1, 0), axis=1)
-    lower = jnp.where((lo > 0)[None, :], lower, 0)
-    return jnp.where((hi > lo)[None, :], upper - lower, 0)
+    # prologue: ok[p] = (#edges of pair p inside the lane's window) >= h
+    def pair_ok(p, e0):
+        e1 = pend_ref[p]
+        cnt = jax.lax.fori_loop(
+            e0, e1,
+            lambda e, c: c + in_window(t_ref[e]).astype(jnp.int32), zero)
+        ok_ref[pl.ds(p, 1), :] = (cnt >= hh).astype(jnp.int32)
+        return e1
 
+    jax.lax.fori_loop(0, n_pairs, pair_ok, jnp.int32(0))
+    cur_ref[...] = alive_ref[...]
 
-def _kernel(ts_ref, te_ref, k_ref, h_ref,           # SMEM scalar prefetch
-            t_ref, src_ref, dst_ref, hpp_ref,       # TEL tables [1, .]
-            ps_ref, pe_ref, vs_ref, ve_ref,         # band tables [1, .]
-            alive_ref,                              # [w_tile, V32] in
-            alive_out_ref, packed_ref, lo_ref, hi_ref, ne_ref, it_ref,
-            *, w_tile: int):
-    q = pl.program_id(0)
-    base = q * w_tile
-    ts = ts_ref[pl.ds(base, w_tile)].reshape(w_tile, 1)
-    te = te_ref[pl.ds(base, w_tile)].reshape(w_tile, 1)
-    kk = k_ref[pl.ds(base, w_tile)].reshape(w_tile, 1)
-    hh = h_ref[pl.ds(base, w_tile)].reshape(w_tile, 1)
+    def vertex_degree(v, j0):
+        j1 = vend_ref[v]
 
-    t = t_ref[0, :]
-    src = src_ref[0, :]
-    dst = dst_ref[0, :]
-    hpp = hpp_ref[0, :]
-    ps, pe = ps_ref[0, :], pe_ref[0, :]
-    vs, ve = vs_ref[0, :], ve_ref[0, :]
+        def half_pair(j, acc):
+            return acc + (ok_ref[pl.ds(hpp_ref[j], 1), :]
+                          * cur_ref[pl.ds(hpo_ref[j], 1), :])
 
-    # loop-invariant: sentinel edges carry t = int32 min, below every window
-    win = (t[None, :] >= ts) & (t[None, :] <= te)
-
-    def cond(state):
-        return state[2]
+        deg_ref[pl.ds(v, 1), :] = jax.lax.fori_loop(j0, j1, half_pair, zero)
+        return j1
 
     def body(state):
-        cur, _, _, it = state
-        ea = win & jnp.take(cur, src, axis=1) & jnp.take(cur, dst, axis=1)
-        paircnt = _banded_count(ea.astype(jnp.int32), ps, pe)    # [w, P]
-        pairact = (paircnt >= hh).astype(jnp.int32)
-        contrib = jnp.take(pairact, hpp, axis=1)                 # [w, 2P]
-        deg = _banded_count(contrib, vs, ve)                     # [w, V32]
-        new = cur & (deg >= kk)
-        return new, ea, jnp.any(new != cur), it + jnp.int32(1)
+        it, _ = state
+        jax.lax.fori_loop(0, n_verts, vertex_degree, jnp.int32(0))
+        cur = cur_ref[...]
+        new = jnp.where(deg_ref[...] >= kk, cur, 0)
+        cur_ref[...] = new
+        return it + 1, jnp.max(cur - new)
 
-    alive0 = alive_ref[...]
-    ea0 = jnp.zeros(win.shape, dtype=jnp.bool_)
-    alive, ea, _, iters = jax.lax.while_loop(
-        cond, body, (alive0, ea0, jnp.bool_(True), jnp.int32(0)))
+    iters, _ = jax.lax.while_loop(lambda s: s[1] > 0, body,
+                                  (jnp.int32(0), jnp.int32(1)))
 
-    alive_out_ref[...] = alive
-    ne_ref[...] = jnp.sum(ea, axis=1, dtype=jnp.int32).reshape(w_tile, 1)
-    lo_ref[...] = jnp.min(jnp.where(ea, t[None, :], _I32_MAX),
-                          axis=1).reshape(w_tile, 1)
-    hi_ref[...] = jnp.max(jnp.where(ea, t[None, :], _I32_MIN),
-                          axis=1).reshape(w_tile, 1)
-    it_ref[0, 0] = iters
-    # uint32 bitmask pack, LSB-first (engine._pack_u32 layout): bit sums of
-    # distinct powers of two are exact mod 2^32 in int32, bitcast in wrapper
-    v32 = alive.shape[1]
-    bits = alive.astype(jnp.int32).reshape(w_tile, v32 // 32, 32)
-    shift = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 32), 2)
-    packed_ref[...] = jnp.sum(bits << shift, axis=2, dtype=jnp.int32)
+    # epilogue: edge activity of the fixpoint -> edge count + TTI lo/hi
+    def pair_stats(p, carry):
+        e0, ne, lo, hi = carry
+        e1 = pend_ref[p]
+        both = (cur_ref[pl.ds(pu_ref[p], 1), :]
+                * cur_ref[pl.ds(pv_ref[p], 1), :]) > 0
+
+        def edge(e, c):
+            ne, lo, hi = c
+            tv = t_ref[e]
+            ea = in_window(tv) & both
+            return (ne + ea.astype(jnp.int32),
+                    jnp.where(ea, jnp.minimum(lo, tv), lo),
+                    jnp.where(ea, jnp.maximum(hi, tv), hi))
+
+        ne, lo, hi = jax.lax.fori_loop(e0, e1, edge, (ne, lo, hi))
+        return e1, ne, lo, hi
+
+    _, ne, lo, hi = jax.lax.fori_loop(
+        0, n_pairs, pair_stats,
+        (jnp.int32(0), zero, jnp.full((1, LANES), _I32_MAX, jnp.int32),
+         jnp.full((1, LANES), _I32_MIN, jnp.int32)))
+    st_ref[0:1, :] = ne
+    st_ref[1:2, :] = lo
+    st_ref[2:3, :] = hi
+    st_ref[3:4, :] = jnp.broadcast_to(iters, (1, LANES))
+    st_ref[4:STAT_ROWS, :] = jnp.zeros((STAT_ROWS - 4, LANES), jnp.int32)
 
 
-def wave_peel_pallas(ts, te, k, h, t2, src2, dst2, hpp2,
-                     ps2, pe2, vs2, ve2, alive,
-                     *, w_tile: int, interpret: bool):
+def wave_peel_pallas(tables, prm, alive, *, n_pairs: int, n_verts: int,
+                     pair_rows: int, interpret: bool):
     """Raw fused call over pre-padded arrays.
 
-    ts/te/k/h: [W_pad] int32 (W_pad a multiple of w_tile); t2/src2/dst2:
-    [1, E_pad]; hpp2: [1, HP_pad]; ps2/pe2: [1, P]; vs2/ve2: [1, V32];
-    alive: [W_pad, V32] bool with V32 a multiple of 32.
+    tables: seven 1-D int32 SMEM tables (t, pair_end, pair_u, pair_v,
+    vertex_end, hp_other, hp_pair) — see ``ops.make_fused_wave_step``;
+    prm: [8, W_pad] int32 rows ts, te, k, h; alive: [V_pad, W_pad] int32
+    0/1 lane slab (V_pad a multiple of 8, W_pad of ``LANES``).
 
-    Returns (alive [W_pad, V32] bool, packed [W_pad, V32//32] int32,
-    lo/hi/ne [W_pad, 1] int32, iters [W_tiles, 1] int32).
+    Returns (alive [V_pad, W_pad] int32, stats [8, W_pad] int32 with rows
+    n_edges, tti_lo, tti_hi, iters).
     """
-    w_pad, v32 = alive.shape
-    n_tiles = w_pad // w_tile
-    e_pad = t2.shape[1]
-    hp_pad = hpp2.shape[1]
-    p_dim = ps2.shape[1]
-
-    full = lambda w: pl.BlockSpec((1, w), lambda q, *pref: (0, 0))  # noqa: E731
-    lane = lambda w: pl.BlockSpec((w_tile, w), lambda q, *pref: (q, 0))  # noqa: E731
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n_tiles,),
-        in_specs=[
-            full(e_pad), full(e_pad), full(e_pad),   # t, src, dst
-            full(hp_pad),                            # hp_pair
-            full(p_dim), full(p_dim),                # pair starts/ends
-            full(v32), full(v32),                    # vertex starts/ends
-            lane(v32),                               # alive
-        ],
-        out_specs=[
-            lane(v32),                               # alive out
-            lane(v32 // 32),                         # packed
-            lane(1), lane(1), lane(1),               # lo, hi, ne
-            pl.BlockSpec((1, 1), lambda q, *pref: (q, 0)),  # iters
-        ],
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((w_pad, v32), jnp.bool_),
-        jax.ShapeDtypeStruct((w_pad, v32 // 32), jnp.int32),
-        jax.ShapeDtypeStruct((w_pad, 1), jnp.int32),
-        jax.ShapeDtypeStruct((w_pad, 1), jnp.int32),
-        jax.ShapeDtypeStruct((w_pad, 1), jnp.int32),
-        jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-    ]
+    v_pad, w_pad = alive.shape
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    slab = pl.BlockSpec((v_pad, LANES), lambda q: (0, q))
+    stat = pl.BlockSpec((STAT_ROWS, LANES), lambda q: (0, q))
     return pl.pallas_call(
-        functools.partial(_kernel, w_tile=w_tile),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        functools.partial(_kernel, n_pairs=n_pairs, n_verts=n_verts),
+        grid=(w_pad // LANES,),
+        in_specs=[smem] * len(tables) + [stat, slab],
+        out_specs=[slab, stat],
+        out_shape=[jax.ShapeDtypeStruct((v_pad, w_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((STAT_ROWS, w_pad), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((pair_rows, LANES), jnp.int32),
+                        pltpu.VMEM((v_pad, LANES), jnp.int32)],
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(ts, te, k, h, t2, src2, dst2, hpp2, ps2, pe2, vs2, ve2, alive)
+            dimension_semantics=("parallel",)),
+        name="wave_peel",
+    )(*tables, prm, alive)

@@ -1,18 +1,24 @@
 """Dispatching wrapper + cost model for the fused wave-peel kernel.
 
-``make_fused_wave_step`` does the host-side analysis once per TEL
-(segment-bound tables from the canonical sort, 128-lane padding, VMEM
-budgeting) and returns a jitted ``step(alive, ts, te, k, h) ->
-StepResult`` closure, or ``None`` when the TEL's VMEM working set
-exceeds the budget — callers (``core.wave.make_wave_step_fn``) fall
-back to the XLA composite, which is exactly the regime where the
-engine's window truncation should have kept E small in the first place.
+``make_fused_wave_step`` does the host-side analysis once per TEL (the
+window's live edges grouped by pair, its local pairs and vertices, the
+half-pair tables sorted by local vertex) and returns a jitted
+``step(alive, ts, te, k, h) -> StepResult`` closure, or ``None`` when
+the tables exceed the kernel's SMEM capacity or its VMEM working set
+exceeds the budget — callers (``core.wave.make_wave_step_fn``) then use
+the XLA composite.
 
-``fused_step_cost`` is the structural HBM/FLOP model used by
-``benchmarks/bench_wave.py`` and ``benchmarks/roofline.py``: the fused
-step's HBM bytes are *iteration-independent* (tables once per W-tile +
-the alive slab + outputs), which is the whole point vs the unfused
-chain's per-iteration [W, E] round-trips.
+Vertices with no live edge in the TEL never reach the kernel: their
+degree is 0 in every iteration, so they survive iff ``k <= 0`` and can
+only change state on the first iteration.  The wrapper applies that rule
+directly and folds it into the iteration count, which keeps the step
+bit-identical to the composite on every ``StepResult`` field.
+
+``fused_step_cost`` is the structural HBM/op model used by
+``benchmarks/bench_wave.py``: the fused step's HBM bytes are
+*iteration-independent* (tables once per lane tile + the lane slab in
+and out), which is the whole point vs the unfused chain's per-iteration
+[W, E] round-trips.
 """
 
 from __future__ import annotations
@@ -25,70 +31,105 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.segdeg.ops import on_tpu
-from repro.kernels.wave_peel.kernel import segment_bounds, wave_peel_pallas
+from repro.kernels.wave_peel.kernel import LANES, wave_peel_pallas
 
-_I32_MIN = np.iinfo(np.int32).min
-
-# Per-core VMEM is ~16 MB on current TPUs; leave headroom for Mosaic's
-# own temporaries (the loop carries alive + ea, plus one cumsum buffer).
+# Per-core VMEM is 16 MiB of default scoped space on v5e; leave headroom
+# for Mosaic's own temporaries.
 DEFAULT_VMEM_BUDGET = 12 << 20
+# SMEM is 1 MiB on v5e; the index tables may take at most this much.
+SMEM_TABLE_BUDGET = 768 << 10
 
 
 def _align(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
-def fused_step_vmem_bytes(num_edges: int, num_pairs: int, num_halfpairs: int,
-                          v32: int, w_tile: int) -> int:
-    """Worst-case VMEM working set of one grid program (bytes)."""
-    e = _align(num_edges, 128)
-    hp = _align(num_halfpairs, 128)
-    p = _align(num_pairs, 128)
-    tables = 4 * (3 * e + hp + 2 * p + 2 * v32)
-    # per-lane live arrays: win + ea + carry copy (bool ~ int8) and the
-    # int32 cumsum / paircnt / contrib / deg intermediates
-    per_lane = 3 * e + 4 * (e + 2 * p + hp + 2 * v32)
-    return tables + w_tile * per_lane
+def fused_step_smem_bytes(num_edges: int, num_pairs: int,
+                          num_vertices: int) -> int:
+    """SMEM taken by the index tables of a window with that many live
+    edges, local pairs and local vertices."""
+    return 4 * (max(num_edges, 1) + 3 * max(num_pairs, 1)
+                + max(num_vertices, 1) + 4 * max(num_pairs, 1))
 
 
-def fused_step_cost(num_edges: int, num_pairs: int, num_halfpairs: int,
-                    num_vertices: int, wave: int, *, w_tile: int = 8,
-                    iters: int = 1) -> dict:
-    """Structural cost model of one fused step (per-device).
+def fused_step_vmem_bytes(num_pairs: int, num_vertices: int) -> int:
+    """VMEM working set of one grid program (bytes): the lane slab in and
+    out (double-buffered by the pipeline), the degree and pair-ok
+    scratch, and the parameter / stats blocks."""
+    v = _align(max(num_vertices, 1), 8)
+    p = _align(max(num_pairs, 1), 8)
+    return 4 * LANES * (4 * v + v + p + 4 * 8)
 
-    HBM bytes are iteration-independent: each W-tile program streams the
-    TEL + band tables once and the lane slab in/out once; every fixpoint
-    intermediate stays in VMEM.  FLOPs scale with ``iters`` (compares,
-    cumsums and gathers counted as 1 op/element).
+
+def fused_step_cost(num_edges: int, num_pairs: int, num_vertices: int,
+                    wave: int, *, iters: int = 1) -> dict:
+    """Structural cost model of one fused step (per device), in terms of
+    the window's live edges, local pairs and local vertices.
+
+    HBM bytes are iteration-independent: each lane-tile program reads
+    the tables once and the lane slab in and out once; every fixpoint
+    intermediate stays in VMEM.  Ops scale with ``iters`` (one row op of
+    ``LANES`` lanes per half-pair and per vertex each iteration, one per
+    edge in the prologue and epilogue).
     """
-    v32 = _align(max(num_vertices, 1), 32)
-    e = _align(num_edges, 128)
-    hp = _align(num_halfpairs, 128)
-    p = _align(num_pairs, 128)
-    w_pad = _align(max(wave, 1), w_tile)
-    tiles = w_pad // w_tile
-    table_bytes = 4.0 * (3 * e + hp + 2 * p + 2 * v32)
-    lane_bytes = float(w_pad) * (2 * v32            # alive in + out (bool)
-                                 + 4 * (v32 // 32)  # packed words
-                                 + 4 * 3) + tiles * 4.0  # lo/hi/ne + iters
-    scalar_bytes = 4.0 * 4 * w_pad                  # ts/te/k/h prefetch
-    flops_per_iter = float(w_pad) * (
-        5.0 * e            # window compare x2, two gathers, 3-way and
-        + 2.0 * e          # edge-axis cumsum + boundary diffs
-        + 3.0 * p          # pair-count gather/compare/threshold
-        + 3.0 * hp         # contrib gather + halfpair cumsum
-        + 3.0 * v32)       # degree diff + k compare + and
+    v = _align(max(num_vertices, 1), 8)
+    w_pad = _align(max(wave, 1), LANES)
+    tiles = w_pad // LANES
+    table_bytes = float(fused_step_smem_bytes(num_edges, num_pairs,
+                                              num_vertices))
+    lane_bytes = 4.0 * w_pad * (2 * v + 2 * 8)
+    ops_per_iter = float(w_pad) * (2 * 2 * num_pairs + 2 * num_vertices)
+    ops_once = float(w_pad) * (2 * 3 * num_edges + 2 * num_pairs)
     return {
-        "bytes_per_step": tiles * table_bytes + lane_bytes + scalar_bytes,
+        "bytes_per_step": tiles * table_bytes + lane_bytes,
         "bytes_per_iter_hbm": 0.0,
-        "flops_per_iter": flops_per_iter,
-        "flops_per_step": flops_per_iter * max(int(iters), 1),
-        "vmem_bytes": fused_step_vmem_bytes(num_edges, num_pairs,
-                                            num_halfpairs, v32, w_tile),
+        "flops_per_iter": ops_per_iter,
+        "flops_per_step": ops_once + ops_per_iter * max(int(iters), 1),
+        "vmem_bytes": fused_step_vmem_bytes(num_pairs, num_vertices),
     }
 
 
-def make_fused_wave_step(tel, num_vertices: int, *, w_tile: int = 8,
+def _window_tables(tel):
+    """Host-side compaction of a (possibly padded) TEL to its live edges.
+
+    Returns (verts, tables, n_edges, n_pairs): ``verts`` are the global
+    ids of the local vertices (sorted); ``tables`` the kernel's seven
+    1-D int32 SMEM tables, each at least one entry long."""
+    num_pairs = int(tel.pair_u.shape[0])
+    pair_id = np.asarray(tel.pair_id)
+    live = pair_id < num_pairs          # sentinel edges carry pair_id == P
+    pid = pair_id[live]
+    t = np.asarray(tel.t)[live]
+    src = np.asarray(tel.src)[live]
+    dst = np.asarray(tel.dst)[live]
+    if np.any(pid[1:] < pid[:-1]):
+        raise ValueError("TEL edges are not grouped by pair_id")
+    first = np.ones(pid.size, dtype=bool)
+    first[1:] = pid[1:] != pid[:-1]
+    starts = np.flatnonzero(first)
+    pair_end = np.append(starts[1:], pid.size)
+    verts = np.unique(np.concatenate([src[first], dst[first]]))
+    pu = np.searchsorted(verts, src[first])
+    pv = np.searchsorted(verts, dst[first])
+    n_pairs = int(starts.size)
+    hp_vert = np.concatenate([pu, pv])
+    order = np.argsort(hp_vert, kind="stable")
+    hp_other = np.concatenate([pv, pu])[order]
+    hp_pair = np.concatenate([np.arange(n_pairs)] * 2)[order]
+    vert_end = np.searchsorted(hp_vert[order], np.arange(verts.size),
+                               side="right")
+
+    def table(a):
+        out = np.zeros(max(a.size, 1), np.int32)
+        out[:a.size] = a
+        return jnp.asarray(out)
+
+    tables = tuple(table(a) for a in (t, pair_end, pu, pv, vert_end,
+                                      hp_other, hp_pair))
+    return verts, tables, int(pid.size), n_pairs
+
+
+def make_fused_wave_step(tel, num_vertices: int, *,
                          interpret: Optional[bool] = None,
                          donate: bool = False,
                          vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET):
@@ -96,69 +137,54 @@ def make_fused_wave_step(tel, num_vertices: int, *, w_tile: int = 8,
 
     Returns ``step(alive [W, V] bool, ts, te, k, h) -> StepResult`` (the
     ``core.wave`` result type, bit-identical to the composite), or
-    ``None`` when the per-program VMEM working set exceeds the budget.
-    ``interpret=None`` auto-resolves: compiled on TPU, interpret mode
-    elsewhere (the CPU correctness gates).
+    ``None`` when the window's tables exceed the SMEM capacity or the
+    per-program VMEM working set exceeds the budget.  ``interpret=None``
+    auto-resolves: compiled on TPU, interpret mode elsewhere (the CPU
+    correctness gates).
     """
     interp = (not on_tpu()) if interpret is None else bool(interpret)
     v = int(num_vertices)
-    v32 = _align(max(v, 1), 32)
-    e = int(tel.t.shape[0])
-    p = int(tel.pair_u.shape[0])
-    hp = int(tel.hp_src.shape[0])
-    if not interp and fused_step_vmem_bytes(e, p, hp, v32, w_tile) > \
-            int(vmem_budget_bytes):
+    verts, tables, n_e, n_p = _window_tables(tel)
+    n_v = int(verts.size)
+    if not interp and (
+            fused_step_smem_bytes(n_e, n_p, n_v) > SMEM_TABLE_BUDGET
+            or fused_step_vmem_bytes(n_p, n_v) > int(vmem_budget_bytes)):
         return None
-
-    # host-side band analysis (once per TEL; the canonical sort makes
-    # every segment a contiguous run — no scatter on device)
-    pair_id = np.asarray(tel.pair_id)
-    hp_src = np.asarray(tel.hp_src)
-    ps, pe = segment_bounds(pair_id, p)
-    vs, ve = segment_bounds(hp_src, v32)
-
-    e_pad = _align(max(e, 1), 128)
-    hp_pad = _align(max(hp, 1), 128)
-    p_pad = _align(max(p, 1), 128)
-
-    def pad_to(a, n, fill=0):
-        a = np.asarray(a)
-        out = np.full(n, fill, dtype=np.int32)
-        out[:a.shape[0]] = a
-        return jnp.asarray(out[None, :])
-
-    # sentinel-padded tails: t = int32 min fails every window test, so
-    # padded edges are dead; padded pair/vertex slots get empty ranges
-    t2 = pad_to(tel.t, e_pad, _I32_MIN)
-    src2 = pad_to(tel.src, e_pad)
-    dst2 = pad_to(tel.dst, e_pad)
-    hpp2 = pad_to(tel.hp_pair, hp_pad)
-    ps2 = pad_to(ps, p_pad)
-    pe2 = pad_to(pe, p_pad)
-    vs2 = jnp.asarray(vs[None, :])
-    ve2 = jnp.asarray(ve[None, :])
+    v_pad = _align(max(n_v, 1), 8)
+    pair_rows = _align(max(n_p, 1), 8)
+    verts_d = jnp.asarray(verts.astype(np.int32))
+    isolated = np.ones(v, dtype=bool)
+    isolated[verts] = False
+    isolated_d = jnp.asarray(isolated)
 
     def _step(alive, ts, te, k, h):
-        from repro.core.wave import StepResult
+        from repro.core.wave import StepResult, _pack_u32
 
         w = alive.shape[0]
-        w_pad = _align(max(w, 1), w_tile)
+        w_pad = _align(max(w, 1), LANES)
         # padding lanes carry the empty window (ts=0 > te=-1) and k=h=1
-        # with an all-dead mask: they converge on iteration 1 and never
-        # inflate the per-tile iteration count
+        # with an all-dead mask: they converge on iteration 1
         def lanes(x, fill):
             x = jnp.broadcast_to(jnp.asarray(x, jnp.int32), (w,))
             return jnp.pad(x, (0, w_pad - w), constant_values=fill)
 
-        alive_p = jnp.pad(alive, ((0, w_pad - w), (0, v32 - v)))
-        a_out, packed, lo, hi, ne, itrs = wave_peel_pallas(
-            lanes(ts, 0), lanes(te, -1), lanes(k, 1), lanes(h, 1),
-            t2, src2, dst2, hpp2, ps2, pe2, vs2, ve2, alive_p,
-            w_tile=w_tile, interpret=interp)
-        return StepResult(
-            a_out[:w, :v],
-            jax.lax.bitcast_convert_type(packed, jnp.uint32)[:w],
-            lo[:w, 0], hi[:w, 0], ne[:w, 0], jnp.max(itrs))
+        k_l = jnp.broadcast_to(jnp.asarray(k, jnp.int32), (w,))
+        prm = jnp.zeros((8, w_pad), jnp.int32)
+        prm = prm.at[:4].set(jnp.stack([lanes(ts, 0), lanes(te, -1),
+                                        lanes(k, 1), lanes(h, 1)]))
+        slab = jnp.pad(alive[:, verts_d].T.astype(jnp.int32),
+                       ((0, v_pad - n_v), (0, w_pad - w)))
+        out, st = wave_peel_pallas(tables, prm, slab, n_pairs=n_p,
+                                   n_verts=n_v, pair_rows=pair_rows,
+                                   interpret=interp)
+        # vertices without live edges: degree 0, so they survive iff
+        # k <= 0 and change at most once (on the first iteration)
+        rest = alive & (k_l <= 0)[:, None]
+        new = rest.at[:, verts_d].set(out[:n_v, :w].T > 0)
+        moved = jnp.any(alive & isolated_d[None, :] & (k_l > 0)[:, None])
+        iters = jnp.maximum(jnp.max(st[3]), jnp.where(moved, 2, 1))
+        return StepResult(new, _pack_u32(new, v), st[1, :w], st[2, :w],
+                          st[0, :w], iters.astype(jnp.int32))
 
     jitted = jax.jit(_step, donate_argnums=(0,)) if donate \
         else jax.jit(_step)
@@ -169,10 +195,8 @@ def make_fused_wave_step(tel, num_vertices: int, *, w_tile: int = 8,
 
     step.backend = "pallas"
     step.interpret = interp
-    step.w_tile = w_tile
-    step.cost = fused_step_cost(e, p, hp, v, wave=w_tile, w_tile=w_tile)
+    step.local_counts = (n_e, n_p, n_v)
     # operand census for perf_lower's structural assert: nothing
     # [W, E]-shaped ever crosses HBM on this path
-    step.operand_shapes = [tuple(x.shape) for x in
-                           (t2, src2, dst2, hpp2, ps2, pe2, vs2, ve2)]
+    step.operand_shapes = [tuple(x.shape) for x in tables]
     return step

@@ -411,6 +411,9 @@ def main():
 
     from repro.data import TCQRequestStream
     from repro.graphs import EdgeStream, powerlaw_temporal
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     g = powerlaw_temporal(args.vertices, args.edges, args.span, seed=3)
     lo, hi = g.span

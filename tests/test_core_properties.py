@@ -2,10 +2,7 @@
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: vendored seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import TCQEngine, TemporalGraph, brute_force_query
 from repro.core.oracle import peel_window

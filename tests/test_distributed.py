@@ -20,8 +20,10 @@ from repro.core.distributed import DistributedTCQ, ShardPlan, shard_graph
 from repro.core.graph import _I32_MIN
 from repro.core.oracle import peel_window
 from repro.graphs import planted_cores, powerlaw_temporal
+from repro.launch.mesh import make_mesh
 
 _GATE = os.environ.get("REPRO_DIST_GATE") == "1"
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _check_engine(g, mesh, combine, k, cells):
@@ -45,7 +47,7 @@ def _check_engine(g, mesh, combine, k, cells):
 @pytest.mark.parametrize("combine", ["psum", "rs_ag"])
 def test_wave_on_unit_mesh(combine):
     g = planted_cores(seed=3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     _check_engine(g, mesh, combine, 3, [(1, 40), (5, 30), (10, 20), (1, 15)])
 
 
@@ -148,7 +150,7 @@ def test_engine_mesh_unit_equivalence(combine):
     (k, h, window), plus re-query after an ingest epoch."""
     g = powerlaw_temporal(100, 900, 80, seed=7)
     plain = TCQEngine(g, cache=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = TCQEngine(g, cache=False, mesh=mesh, combine=combine)
     _assert_results_equal(eng.query_batch(_REQS), plain.query_batch(_REQS))
     dist = eng.stats()["distributed"]
@@ -173,7 +175,7 @@ def test_engine_mesh_kernel_rung_unit_equivalence():
 
     g = planted_cores(seed=5)
     plain = TCQEngine(g, cache=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     reqs = _REQS[:3]
     want = plain.query_batch(reqs)
     eng = TCQEngine(g, cache=False, mesh=mesh, use_kernel=True)
@@ -188,7 +190,7 @@ def test_service_mesh_unit_equivalence():
     """1x1 mesh TCQService == plain TCQService, with per-shard occupancy
     and collective-bytes surfaced in the pool log."""
     g = powerlaw_temporal(60, 400, 40, seed=2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     svc_p = TCQService(g, cache=False)
     svc_d = TCQService(g, cache=False, mesh=mesh)
     reqs = [dict(k=2, ts=5, te=30), dict(k=3, ts=10, te=40, h=2),
@@ -216,8 +218,9 @@ import numpy as np, jax
 from repro.core.distributed import DistributedTCQ
 from repro.core.oracle import peel_window
 from repro.graphs import planted_cores
+from repro.launch.mesh import make_mesh
 g = planted_cores(seed=3)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for combine in ("psum", "rs_ag"):
     eng = DistributedTCQ(g, mesh, combine=combine)
     ts, te, k = [1, 5, 10, 1], [40, 30, 20, 15], 3
@@ -235,7 +238,7 @@ def test_wave_on_2x4_mesh_subprocess():
     """Real multi-device shard_map semantics (8 fake CPU devices require a
     fresh process: jax locks the device count at first init)."""
     out = subprocess.run([sys.executable, "-c", _SUBPROCESS],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=_ROOT,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
@@ -250,6 +253,7 @@ import json
 import numpy as np, jax
 from repro.core import TCQEngine, TCQService
 from repro.graphs import powerlaw_temporal
+from repro.launch.mesh import make_mesh
 
 cases = json.loads(sys.argv[1])
 g = powerlaw_temporal(100, 900, 80, seed=7)
@@ -269,7 +273,7 @@ def check(got, want, ctx):
 plain = TCQEngine(g, cache=False)
 want = plain.query_batch(reqs)
 for L, M, combine in cases:
-    mesh = jax.make_mesh((L, M), ("data", "model"))
+    mesh = make_mesh((L, M), ("data", "model"))
     eng = TCQEngine(g, cache=False, mesh=mesh, combine=combine)
     check(eng.query_batch(reqs), want, (L, M, combine, "batch"))
     d = eng.stats()["distributed"]
@@ -305,7 +309,7 @@ def run_service(mesh):
 
 base = run_service(None)
 for L, M, combine in cases:
-    mesh = jax.make_mesh((L, M), ("data", "model"))
+    mesh = make_mesh((L, M), ("data", "model"))
     got = run_service(mesh)
     assert base.keys() == got.keys(), (L, M)
     for tid in base:
@@ -328,7 +332,7 @@ def test_mesh_equivalence_subprocess():
     cases = _GATE_CASES if _GATE else _DEFAULT_CASES
     out = subprocess.run(
         [sys.executable, "-c", _MESH_EQUIV, json.dumps(cases)],
-        capture_output=True, text=True, cwd="/root/repo", timeout=600)
+        capture_output=True, text=True, cwd=_ROOT, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout
 
@@ -342,6 +346,7 @@ import jax
 from repro.core import ResilienceConfig, TCQService
 from repro.core.faultinject import FaultPlan, FaultyStep
 from repro.graphs import powerlaw_temporal
+from repro.launch.mesh import make_mesh
 
 g = powerlaw_temporal(64, 192, 128, seed=9)
 lo, hi = g.span
@@ -359,7 +364,7 @@ def digest(tickets):
             for t in sorted(tickets, key=lambda t: t.id)]
 
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 
 
 def drain(wrapper):
@@ -404,7 +409,7 @@ def test_sharded_rung_fault_demotes_one_pool_subprocess():
     'error'), the other pool stays on the fused kernel, and the whole
     drain is bit-identical to the fault-free sharded run."""
     out = subprocess.run([sys.executable, "-c", _SHARD_FAULT],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=_ROOT,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout
@@ -417,8 +422,8 @@ def test_dryrun_smoke_subprocess():
         [sys.executable, "-m", "repro.launch.dryrun", "--smoke",
          "--arch", "gemma2-2b", "--shape", "train_4k,decode_32k",
          "--mesh", "both"],
-        capture_output=True, text=True, cwd="/root/repo", timeout=900,
+        capture_output=True, text=True, cwd=_ROOT, timeout=900,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"})
+             "HOME": os.environ.get("HOME", _ROOT)})
     assert out.returncode == 0, (out.stdout[-1500:], out.stderr[-1500:])
     assert "0 failed" in out.stdout

@@ -115,7 +115,9 @@ def test_shape_cells_match_assignment():
 
 
 def test_microbatch_policy():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     big = get_config("jamba-1.5-large-398b")
     small = get_config("granite-moe-1b-a400m")
     cell = S.SHAPES["train_4k"]

@@ -123,13 +123,12 @@ def test_pack_unpack_single_row():
 
 
 def test_distributed_packed_transfer_matches_bool():
-    import jax
-
     from repro.core.distributed import DistributedTCQ
     from repro.graphs import planted_cores
+    from repro.launch.mesh import make_mesh
 
     g = planted_cores(seed=3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = DistributedTCQ(g, mesh)
     ts, te, k = [1, 5, 10], [40, 30, 20], 3
     alive, lo, hi, ne, _ = eng.query_wave(ts, te, k)

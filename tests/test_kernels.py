@@ -11,10 +11,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: vendored seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.segdeg.kernel import banded_segsum_pallas, required_k_max
 from repro.kernels.segdeg.ops import make_banded_segsum
@@ -170,7 +167,7 @@ def _random_temporal_graph(rng):
     return TemporalGraph.from_edges(u, w, t, num_vertices=v), tmax
 
 
-def _fuzz_fused_vs_composite(seed, *, capacity_padding):
+def _fuzz_fused_vs_composite(seed, *, capacity_padding, max_wave=12):
     from repro.core.graph import pow2_capacity
     from repro.core.wave import make_wave_step_fn, unpack_alive_u32
 
@@ -186,13 +183,12 @@ def _fuzz_fused_vs_composite(seed, *, capacity_padding):
     else:
         nv = g.num_vertices
         tel = g.device_tel()
-    w_tile = int(rng.choice([4, 8]))
-    fused = make_wave_step_fn(tel, nv, use_kernel=True, w_tile=w_tile)
+    fused = make_wave_step_fn(tel, nv, use_kernel=True)
     comp = make_wave_step_fn(tel, nv, use_kernel=False)
     assert fused.backend == "pallas" and fused.interpret
     assert comp.backend == "xla"
 
-    W = int(rng.integers(1, 12))     # rarely a w_tile multiple
+    W = int(rng.integers(1, max_wave))   # rarely a lane-tile multiple
     ts = rng.integers(0, tmax, W).astype(np.int32)
     te = (ts + rng.integers(0, tmax, W)).astype(np.int32)
     empty = rng.random(W) < 0.25     # pipeline-style idle padding lanes
@@ -226,6 +222,16 @@ def test_fused_wave_peel_matches_composite(seed):
 @pytest.mark.parametrize("seed", _FUZZ_SEEDS)
 def test_fused_wave_peel_matches_composite_capacity_padded(seed):
     _fuzz_fused_vs_composite(2000 + seed, capacity_padding=True)
+
+
+@pytest.mark.kernel_gate
+@pytest.mark.parametrize("seed", range(2))
+def test_fused_wave_peel_matches_composite_multi_tile(seed):
+    """Waves wider than one 128-lane tile: several grid programs, each
+    with its own fixpoint loop; max-over-tiles must equal the composite's
+    shared iteration count."""
+    _fuzz_fused_vs_composite(3000 + seed, capacity_padding=bool(seed),
+                             max_wave=300)
 
 
 @pytest.mark.kernel_gate
@@ -264,6 +270,21 @@ def test_fused_vmem_budget_falls_back_to_composite():
     step = make_wave_step_fn(tel, g.num_vertices, use_kernel=True,
                              interpret=False, vmem_budget_bytes=1024)
     assert step.backend == "xla"
+
+
+def test_on_tpu_propagates_backend_errors(monkeypatch):
+    """A backend that fails to initialize must surface, not read as
+    "not a TPU" (which would silently route the chip to CPU paths)."""
+    import jax
+
+    from repro.kernels.segdeg.ops import on_tpu
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        on_tpu()
 
 
 def test_segsum_fns_cached_per_epoch():

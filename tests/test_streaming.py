@@ -23,10 +23,7 @@ under appends, and window clustering.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic env: vendored seeded fallback
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (TCQEngine, TCQService, TemporalGraph,
                         cluster_windows)

@@ -58,16 +58,17 @@ def test_quantize_roundtrip_error_bound():
 def test_compressed_psum_with_error_feedback():
     """On a 1-device axis the compressed psum must equal the input up to
     quantization error, and error feedback must carry the residual."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
-    mesh = jax.make_mesh((1,), ("d",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("d",))
     x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (64,)),
                     jnp.float32)
     err = jnp.zeros_like(x)
-    fn = shard_map(lambda a, e: compressed_psum_exact(a, "d", e),
-                   mesh=mesh, in_specs=(PS(), PS()),
-                   out_specs=(PS(), PS()), check_rep=False)
+    fn = jax.shard_map(lambda a, e: compressed_psum_exact(a, "d", e),
+                       mesh=mesh, in_specs=(PS(), PS()),
+                       out_specs=(PS(), PS()), check_vma=False)
     out, new_err = fn(x, err)
     np.testing.assert_allclose(np.asarray(out + new_err), np.asarray(x),
                                rtol=1e-6, atol=1e-6)
@@ -201,13 +202,15 @@ def test_elastic_restore_across_meshes(tmp_path):
     """Save under one mesh, restore under another (reshard-on-restore)."""
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     tree = {"w": jax.device_put(
         jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
         NamedSharding(mesh1, PS("data", "model")))}
     mgr = CheckpointManager(str(tmp_path))
     mgr.save(1, tree)
-    mesh2 = jax.make_mesh((1,), ("data",))
+    mesh2 = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh2, PS("data", None))}
     out = mgr.restore(tree, shardings=sh)
     np.testing.assert_array_equal(np.asarray(out["w"]),
