@@ -1,0 +1,6 @@
+"""``bytes_synced_per_step`` in the cells on the XLA composite path, whose few long
+requests a window report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("bytes_synced_per_step")

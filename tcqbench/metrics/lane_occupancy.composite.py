@@ -1,0 +1,6 @@
+"""``lane_occupancy`` in the cells on the XLA composite path, whose few long
+requests a window report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("lane_occupancy")

@@ -1,0 +1,6 @@
+"""``peel_step_roofline`` in the cells on the XLA composite path, whose few long
+requests a window report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("peel_step_roofline")

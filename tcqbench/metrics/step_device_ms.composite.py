@@ -1,0 +1,6 @@
+"""``step_device_ms`` in the cells on the XLA composite path, whose few long
+requests a window report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("step_device_ms")
