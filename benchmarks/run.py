@@ -14,10 +14,10 @@ equivalence (including the streaming snapshot-consistency gate) and
 raise (non-zero exit) on divergence, so the harness doubles as a
 regression gate.  With ``REPRO_BENCH_SMOKE=1`` only the gate benches run,
 on shrunken graphs, and the trajectory file is left untouched — that is
-the per-PR CI mode.  The dry-run / roofline tables are produced by
-``python -m repro.launch.dryrun`` and ``python -m benchmarks.roofline``
-(they need the 512-device env and are kept out of this CPU-timing
-harness).
+the per-PR CI mode.  The dry-run tables are produced by
+``python -m repro.launch.dryrun`` (it needs the 512-device env and is
+kept out of this CPU-timing harness); the on-chip benchmark, roofline
+shares included, is ``python3 -m tcqbench``.
 """
 
 from __future__ import annotations

@@ -566,7 +566,8 @@ def make_sharded_step_fn(mesh, arrays, *, num_vertices: int, p_cap: int,
 
 def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
                              interpret: Optional[bool] = None,
-                             vmem_budget_bytes: Optional[int] = None):
+                             vmem_budget_bytes: Optional[int] = None,
+                             on_refuse=None):
     """Fused Pallas peel-to-fixpoint kernel as the per-shard local step.
 
     Only meshes with a trivial model axis qualify (model=1 — edges
@@ -576,7 +577,9 @@ def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
     programs.  On model-sharded meshes callers fall back to the XLA
     composite local step (the ladder logs the unavailable rung).
 
-    Returns None when the kernel itself declines (VMEM budget).
+    Returns None when the kernel itself declines (SMEM or VMEM budget;
+    ``on_refuse`` then gets the budget's name, as in
+    ``make_fused_wave_step``).
     """
     L, m = mesh_shard_counts(mesh)
     if m != 1:
@@ -588,7 +591,8 @@ def make_sharded_kernel_step(mesh, tel, num_vertices: int, *,
               else int(vmem_budget_bytes))
     fused = make_fused_wave_step(tel, num_vertices,
                                  interpret=interpret, donate=False,
-                                 vmem_budget_bytes=budget)
+                                 vmem_budget_bytes=budget,
+                                 on_refuse=on_refuse)
     if fused is None:
         return None
     axes = _all_axes(mesh)
@@ -652,6 +656,7 @@ class ShardedDegradationLadder(DegradationLadder):
         self.events = []
         self.calls = 0
         self.rung = 0
+        self.fallback = None
         self._rng = np.random.default_rng(self.config.seed)
         L, m = mesh_shard_counts(mesh)
         interpret = self.config.interpret
@@ -663,15 +668,19 @@ class ShardedDegradationLadder(DegradationLadder):
                           "band structure; kernel-within-shard needs a "
                           "lane-only mesh")
             else:
+                refused = []
                 try:
                     fused = make_sharded_kernel_step(
                         mesh, tel, num_vertices, interpret=interpret,
-                        vmem_budget_bytes=self.config.vmem_budget_bytes)
+                        vmem_budget_bytes=self.config.vmem_budget_bytes,
+                        on_refuse=refused.append)
                     if fused is None:
-                        self._log("pallas", "vmem_budget", "")
+                        self.fallback = refused[0]
+                        self._log("pallas", self.fallback, "")
                     else:
                         rungs.append(("pallas", fused))
                 except Exception as e:               # pragma: no cover
+                    self.fallback = "build_error"
                     self._log("pallas", "build_error", repr(e))
         rungs.append(("xla", make_sharded_step_fn(
             mesh, arrays, num_vertices=num_vertices, p_cap=p_cap,

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core.graph import DeviceTEL
 from repro.core.results import CoreResult, QueryStats
@@ -101,10 +102,11 @@ class _Slot:
     """One ring stage: a device lane buffer + its in-flight step.
 
     ``lanes[li]`` holds the (QueryState, RowCursor) the lane is serving,
-    or None when free; ``dirty`` marks lanes holding a stale (dead) mask.
+    or None when free; ``dirty`` marks lanes holding a stale (dead) mask;
+    ``step`` is the pool step number of the in-flight step.
     """
 
-    __slots__ = ("buf", "lanes", "dirty", "inflight",
+    __slots__ = ("buf", "lanes", "dirty", "inflight", "step",
                  "_params_np", "_params_dev")
 
     def __init__(self, wave: int, num_vertices: int, buf=None):
@@ -116,6 +118,7 @@ class _Slot:
             [None] * wave
         self.dirty: set = set()
         self.inflight: Optional[StepResult] = None
+        self.step = -1
         # committed (ts, te, k, h) cache for sharded pipelines: the host
         # vectors + their device placements from the last dispatch
         self._params_np = None
@@ -229,8 +232,22 @@ class WavePipeline:
         and its in-flight lanes are *reclaimed mid-pool*: freed at the
         next assemble/retire without result feedback, ready for other
         queries' cells.
+
+        Profiler spans (``jax.profiler.TraceAnnotation``, args ``pool`` =
+        ``pool_stats.pool`` and ``step``): ``tcq.pipeline.run_pool`` over
+        the ring; ``tcq.pipeline.assemble`` / ``.dispatch`` / ``.retire``
+        per phase of each step; ``tcq.pipeline.sync`` around retire's
+        blocking ``device_get``; ``tcq.engine.step_compile`` around the
+        first call of a freshly built step function, where JAX traces,
+        lowers and compiles it (counted in ``pool_stats.step_compiles``).
         """
+        with TraceAnnotation("tcq.pipeline.run_pool", pool=pool_stats.pool):
+            self._run_ring(states, pool_stats, admit)
+
+    def _run_ring(self, states: List[QueryState], pool_stats: QueryStats,
+                  admit: Optional[Callable[[], List[QueryState]]]) -> None:
         W = self.wave
+        pool = pool_stats.pool
         claimable = deque(s for s in states if s.n > 0 and not s.cancelled)
         occupied_total = 0
 
@@ -321,9 +338,20 @@ class WavePipeline:
             te_arr = np.array(te_l, np.int32)
             k_arr = np.array(k_l, np.int32)
             h_arr = np.array(h_l, np.int32)
-            slot.inflight = self._step(
-                slot.buf, *self._commit_params(
-                    slot, (ts_arr, te_arr, k_arr, h_arr)))
+            args = (slot.buf, *self._commit_params(
+                slot, (ts_arr, te_arr, k_arr, h_arr)))
+            step = self._step
+            slot.step = pool_stats.device_steps
+            if getattr(step, "called", False):
+                slot.inflight = step(*args)
+            else:
+                # the flag lives on the step object, which the engine pins
+                # per window-TEL entry: a cache hit's step is already built
+                with TraceAnnotation("tcq.engine.step_compile", pool=pool,
+                                     step=slot.step):
+                    slot.inflight = step(*args)
+                step.called = True
+                pool_stats.step_compiles += 1
             slot.buf = slot.inflight.alive   # donated through; new handle
             pool_stats.device_steps += 1
             nonlocal occupied_total
@@ -333,8 +361,11 @@ class WavePipeline:
         def retire(slot: _Slot) -> None:
             res = slot.inflight
             slot.inflight = None
-            packed, lo, hi, ne, it = jax.device_get(
-                (res.packed, res.tti_lo, res.tti_hi, res.n_edges, res.iters))
+            with TraceAnnotation("tcq.pipeline.sync", pool=pool,
+                                 step=slot.step):
+                packed, lo, hi, ne, it = jax.device_get(
+                    (res.packed, res.tti_lo, res.tti_hi, res.n_edges,
+                     res.iters))
             pool_stats.host_syncs += 1
             pool_stats.bytes_synced += (packed.nbytes + lo.nbytes + hi.nbytes
                                         + ne.nbytes + it.nbytes)
@@ -369,10 +400,18 @@ class WavePipeline:
         # Idle slots reassemble too (a live queue may have admitted new
         # queries since their last dispatch), and the ring only stops
         # once nothing is in flight and the final admit poll is empty.
+        def redispatch(slot: _Slot) -> None:
+            step = pool_stats.device_steps
+            with TraceAnnotation("tcq.pipeline.assemble", pool=pool,
+                                 step=step):
+                assemble(slot)
+            with TraceAnnotation("tcq.pipeline.dispatch", pool=pool,
+                                 step=step):
+                dispatch(slot)
+
         slots = [self._new_slot() for _ in range(self.depth)]
         for slot in slots:
-            assemble(slot)
-            dispatch(slot)
+            redispatch(slot)
         cur = 0
         while True:
             if all(s.inflight is None for s in slots):
@@ -381,9 +420,10 @@ class WavePipeline:
                     break
             slot = slots[cur]
             if slot.inflight is not None:
-                retire(slot)
-            assemble(slot)
-            dispatch(slot)
+                with TraceAnnotation("tcq.pipeline.retire", pool=pool,
+                                     step=slot.step):
+                    retire(slot)
+            redispatch(slot)
             cur = (cur + 1) % self.depth
 
         if pool_stats.device_steps:

@@ -58,6 +58,7 @@ from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence, Tuple, U
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import tcd as tcd_mod
 from repro.core.engine import WavePipeline
@@ -286,13 +287,18 @@ class TCQEngine:
                 self.mesh, arrays, tel, self._v_cap, p_cap=plan.p_cap,
                 combine=self._combine, use_kernel=self._use_kernel,
                 config=self._resilience)
+        refused = []
         if self._use_kernel and self._model_shards == 1:
-            step = make_sharded_kernel_step(self.mesh, tel, self._v_cap)
+            step = make_sharded_kernel_step(self.mesh, tel, self._v_cap,
+                                            on_refuse=refused.append)
             if step is not None:
                 return step
-        return make_sharded_step_fn(
+        step = make_sharded_step_fn(
             self.mesh, arrays, num_vertices=self._v_cap, p_cap=plan.p_cap,
             combine=self._combine, donate=True)
+        if refused:
+            step.fallback = refused[0]
+        return step
 
     def update_graph(self, graph: TemporalGraph) -> int:
         """Install a new graph snapshot (streaming append) under a fresh
@@ -409,7 +415,8 @@ class TCQEngine:
     # -------------------------------------------------------- window slicing
     def _window_tel(self, Ts: int, Te: int, *,
                     graph: Optional[TemporalGraph] = None,
-                    epoch: Optional[int] = None) -> WindowTEL:
+                    epoch: Optional[int] = None,
+                    pool: int = -1) -> WindowTEL:
         """Device TEL truncated to [Ts, Te] for one epoch's snapshot.
 
         Every cell of a query's schedule lies inside [Ts, Te], so both the
@@ -426,7 +433,9 @@ class TCQEngine:
         update can never serve a stale truncation (new epoch, new key),
         while queries pinned to an older epoch — pass ``graph``/``epoch``
         explicitly — keep hitting their snapshot's entries.  Each entry
-        pins the closures and device vertex width it was built with.
+        pins the closures and device vertex width it was built with.  A
+        miss builds the entry inside a ``tcq.engine.window_tel`` profiler
+        span (arg ``pool``: the service pool asking, -1 for none).
         """
         g = self.graph if graph is None else graph
         ep = self.epoch if epoch is None else int(epoch)
@@ -437,6 +446,18 @@ class TCQEngine:
             self._win_cache.move_to_end(key)
             return hit
         self._win_misses += 1
+        with TraceAnnotation("tcq.engine.window_tel", pool=pool):
+            out = self._build_window_tel(g, ep, int(Ts), int(Te))
+        if len(self._win_cache) >= _WINDOW_CACHE_MAX:
+            self._win_cache.popitem(last=False)     # evict least-recent
+            self._win_evictions += 1
+        self._win_cache[key] = out
+        return out
+
+    def _build_window_tel(self, g: TemporalGraph, ep: int, Ts: int,
+                          Te: int) -> WindowTEL:
+        """The cache-miss path of :meth:`_window_tel`: truncation, sort,
+        uploads and the pinned step's build."""
         from repro.core.wave import make_wave_step_fn
 
         aux = self._aux_for(ep, g)
@@ -454,8 +475,8 @@ class TCQEngine:
                                          use_kernel=self._use_kernel,
                                          donate=donate,
                                          resilience=self._resilience)
-            out = WindowTEL(self.tel, self._seg_pair, self._seg_vert,
-                            self._v_cap, e, step)
+            return WindowTEL(self.tel, self._seg_pair, self._seg_vert,
+                             self._v_cap, e, step)
         else:
             bucket = pow2_capacity(e)
             pad = bucket - e
@@ -504,18 +525,15 @@ class TCQEngine:
                                          use_kernel=self._use_kernel,
                                          donate=donate,
                                          resilience=self._resilience)
-            out = WindowTEL(tel, seg_pair, aux.seg_vert, aux.v_cap, e, step)
-        if len(self._win_cache) >= _WINDOW_CACHE_MAX:
-            self._win_cache.popitem(last=False)     # evict least-recent
-            self._win_evictions += 1
-        self._win_cache[key] = out
-        return out
+            return WindowTEL(tel, seg_pair, aux.seg_vert, aux.v_cap, e,
+                             step)
 
     # ------------------------------------------------------------ pool seam
     def make_pool(self, lo: int, hi: int, *,
                   graph: Optional[TemporalGraph] = None,
                   epoch: Optional[int] = None, num_queries: int = 1,
-                  wave: Union[int, str] = "auto", depth: int = 2):
+                  wave: Union[int, str] = "auto", depth: int = 2,
+                  pool: int = -1):
         """Window TEL + lane pipeline for one pool run — the single seam
         ``query``/``query_batch``/``TCQService.pump`` build pools
         through, so the mesh routing decision lives in one place.
@@ -527,7 +545,8 @@ class TCQEngine:
         shard_map step, with W autotuned (or rounded up) to a multiple
         of the lane-axis size.
         """
-        wt = self._window_tel(int(lo), int(hi), graph=graph, epoch=epoch)
+        wt = self._window_tel(int(lo), int(hi), graph=graph, epoch=epoch,
+                              pool=pool)
         if self.mesh is None:
             if wave == "auto":
                 wave = autotune_wave(wt.num_vertices, wt.window_edges,

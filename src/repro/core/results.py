@@ -40,8 +40,8 @@ class QueryStats:
 
     For queries served through ``TCQEngine.query_batch`` the pipeline is
     shared, so the device-side counters (device_steps, host_syncs,
-    bytes_synced, peel_iters, lane_refills, occupancy, wall_time_s)
-    describe the whole batch and are reported identically on every
+    bytes_synced, peel_iters, lane_refills, occupancy, step_compiles,
+    wall_time_s) describe the whole batch and are reported identically on every
     member query; schedule counters (cells_*, pruned_*, duplicates)
     remain query-local.
     """
@@ -71,6 +71,8 @@ class QueryStats:
     wall_time_s: float = 0.0
     collective_bytes: int = 0     # degree-combine wire bytes (sharded pools)
     shard_occupancy: Optional[List[float]] = None  # per-lane-shard occupancy
+    pool: int = -1                # service pool sequence number (-1: none)
+    step_compiles: int = 0        # first calls of freshly built step fns
 
     def absorb_pool(self, pool_stats: "QueryStats", *, window_edges: int,
                     batch_size: int) -> None:
@@ -88,6 +90,8 @@ class QueryStats:
         self.occupancy = pool_stats.occupancy
         self.collective_bytes = pool_stats.collective_bytes
         self.shard_occupancy = pool_stats.shard_occupancy
+        self.pool = pool_stats.pool
+        self.step_compiles = pool_stats.step_compiles
 
     @property
     def pruned_total(self) -> int:

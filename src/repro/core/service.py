@@ -47,6 +47,7 @@ from collections import Counter, deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import wal as walmod
 from repro.core.graph import TemporalGraph
@@ -104,7 +105,9 @@ class TCQTicket:
     pair drives both pool formation (EDF head-of-line) and in-pool lane
     claiming (:class:`~repro.core.scheduler.QueryState`'s EDF key).
     Lifecycle: ``queued`` → ``running`` → one of
-    :data:`TERMINAL_STATUSES`.
+    :data:`TERMINAL_STATUSES`.  ``pool`` is the sequence number of the
+    pool that served it (``pool_log``'s and the profiler spans' ``pool``;
+    -1 while none has).
     """
 
     id: int
@@ -123,6 +126,7 @@ class TCQTicket:
     done_s: Optional[float] = None
     result: Optional[TCQResult] = None
     state: Optional[QueryState] = None
+    pool: int = -1
 
     @property
     def done(self) -> bool:
@@ -258,6 +262,7 @@ class TCQService:
         self.completed: List[TCQTicket] = []
         self._next_id = 0
         self.pool_log: List[Dict] = []      # one record per pool run
+        self._next_pool = 0
         if (self.wal is not None
                 and not walmod.list_snapshots(self.wal.dir)):
             # genesis checkpoint: a directory with no snapshot would make
@@ -466,7 +471,8 @@ class TCQService:
 
     def _finalize(self, tk: TCQTicket, num_vertices: int,
                   done_s: float) -> None:
-        cores = tk.state.decode_results(num_vertices)
+        with TraceAnnotation("tcq.service.finalize", pool=tk.pool):
+            cores = tk.state.decode_results(num_vertices)
         st = tk.state.stats
         tk.result = TCQResult(list(cores.values()), st)
         tk.done_s = done_s
@@ -488,6 +494,13 @@ class TCQService:
         by queries admitted after it, so per-ticket latency is honest
         even when sustained arrivals keep one pool alive.  Returns []
         when nothing resolved and nothing is pending.
+
+        The pool runs inside a ``tcq.service.pump`` profiler span (args
+        ``pool``, its sequence number, and ``members`` at formation); its
+        ``pool_log`` record carries ``pool``, ``window_tel_miss`` (0/1),
+        ``step_compiles`` and ``fallback`` (why a wanted fused kernel
+        gave way to the composite: "smem_tables" | "vmem_budget" |
+        "build_error", else None).
         """
         if poll is not None:
             poll(self)
@@ -521,16 +534,33 @@ class TCQService:
         members = next(
             [cand[i] for i in c] for c in clusters
             if any(cand[i] is head for i in c))
+        pool = self._next_pool
+        self._next_pool += 1
         for tk in members:
             self._pending.remove(tk)
+            tk.pool = pool
+        with TraceAnnotation("tcq.service.pump", pool=pool,
+                             members=len(members)):
+            self._serve_pool(pool, head.graph, epoch, members, poll)
+        fresh, self._fresh = self._fresh, []
+        return members + fresh
+
+    def _serve_pool(self, pool: int, graph: TemporalGraph, epoch: int,
+                    members: List[TCQTicket],
+                    poll: Optional[Callable[["TCQService"], None]]
+                    ) -> None:
+        """Run one pool over ``members`` (a list that grows with
+        mid-flight admissions) and append its ``pool_log`` record."""
         self._inflight = members    # same list object: grows with admits
         pool_lo = min(tk.window[0] for tk in members)
         pool_hi = max(tk.window[1] for tk in members)
+        misses = self.engine._win_misses
         pipe, wt, wave = self.engine.make_pool(
-            pool_lo, pool_hi, graph=head.graph, epoch=epoch,
-            num_queries=len(members), wave=self.wave, depth=self.depth)
+            pool_lo, pool_hi, graph=graph, epoch=epoch,
+            num_queries=len(members), wave=self.wave, depth=self.depth,
+            pool=pool)
         states = [self._make_state(tk) for tk in members]
-        pool_stats = QueryStats()
+        pool_stats = QueryStats(pool=pool)
         t0 = time.perf_counter()
 
         def admit() -> List[QueryState]:
@@ -554,6 +584,7 @@ class TCQService:
                 if (tk.epoch == epoch and tk.window[0] >= pool_lo
                         and tk.window[1] <= pool_hi):
                     self._pending.remove(tk)
+                    tk.pool = pool
                     members.append(tk)
                     st = self._make_state(tk)
                     # a mid-flight arrival fully served by the cache
@@ -578,9 +609,8 @@ class TCQService:
         self._inflight = []
         # drop window TELs / pair tables of epochs no ticket pins anymore
         self.engine.retire_epochs({t.epoch for t in self._pending})
-        fresh, self._fresh = self._fresh, []
         self.pool_log.append({
-            "epoch": epoch, "window": (pool_lo, pool_hi),
+            "pool": pool, "epoch": epoch, "window": (pool_lo, pool_hi),
             "members": len(members), "wave": wave,
             "admitted_midflight": pool_stats.admissions,
             "window_edges": wt.window_edges,
@@ -592,6 +622,9 @@ class TCQService:
                               for tk in members),
             "backend": getattr(wt.step_fn, "backend", "?"),
             "interpret": bool(getattr(wt.step_fn, "interpret", False)),
+            "fallback": getattr(wt.step_fn, "fallback", None),
+            "window_tel_miss": int(self.engine._win_misses > misses),
+            "step_compiles": pool_stats.step_compiles,
             "wall_s": done_s - t0,
         })
         if pool_stats.shard_occupancy is not None:
@@ -599,7 +632,6 @@ class TCQService:
                 pool_stats.shard_occupancy
             self.pool_log[-1]["collective_bytes"] = \
                 pool_stats.collective_bytes
-        return members + fresh
 
     def run_until_idle(self, poll: Optional[Callable] = None
                        ) -> List[TCQTicket]:

@@ -397,6 +397,9 @@ class DegradationLadder:
         self.events = []            # [{rung, reason, detail, call}]
         self.calls = 0
         self.rung = 0
+        # why a wanted Pallas rung never built: "smem_tables",
+        # "vmem_budget" or "build_error" (None: built, or not wanted)
+        self.fallback = None
         self._rng = np.random.default_rng(self.config.seed)
         if self.config.interpret is not None:
             interpret = self.config.interpret
@@ -408,17 +411,22 @@ class DegradationLadder:
             budget = (DEFAULT_VMEM_BUDGET
                       if self.config.vmem_budget_bytes is None
                       else int(self.config.vmem_budget_bytes))
+            refused = []
             try:
                 fused = make_fused_wave_step(tel, num_vertices,
                                              interpret=interpret,
                                              donate=False,
-                                             vmem_budget_bytes=budget)
+                                             vmem_budget_bytes=budget,
+                                             on_refuse=refused.append)
                 if fused is None:
-                    self._log("pallas", "vmem_budget",
-                              f"budget={budget} bytes")
+                    self.fallback = refused[0]
+                    self._log("pallas", self.fallback,
+                              f"budget={budget} bytes"
+                              if self.fallback == "vmem_budget" else "")
                 else:
                     rungs.append(("pallas", fused))
-            except Exception as e:                   # pragma: no cover
+            except Exception as e:
+                self.fallback = "build_error"
                 self._log("pallas", "build_error", repr(e))
         rungs.append(("xla", _make_xla_step(tel, num_vertices,
                                             seg_pair=seg_pair,
@@ -506,11 +514,13 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
     use_kernel=True routes through the fused Pallas peel-to-fixpoint
     kernel (interpret mode off-TPU unless ``interpret`` says otherwise);
     False through the XLA composite; None (default) auto-dispatches —
-    compiled Pallas on TPU, XLA elsewhere.  A TEL whose VMEM working set
-    exceeds the kernel budget falls back to the composite (the window
-    truncation normally keeps E far below that).  ``donate=True`` donates
-    the alive buffer (the pipeline's persistent lane slab); leave False
-    when the caller reuses its buffer across calls.
+    compiled Pallas on TPU, XLA elsewhere.  A TEL whose index tables
+    exceed the kernel's SMEM budget, or whose VMEM working set exceeds
+    its VMEM budget, falls back to the composite, whose ``.fallback``
+    then names the budget ("smem_tables" | "vmem_budget").
+    ``donate=True`` donates the alive buffer (the pipeline's persistent
+    lane slab); leave False when the caller reuses its buffer across
+    calls.
 
     With ``resilience`` set, the returned step is a
     :class:`DegradationLadder` over the same lowerings (Pallas -> XLA ->
@@ -541,13 +551,18 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
 
         budget = (DEFAULT_VMEM_BUDGET if vmem_budget_bytes is None
                   else int(vmem_budget_bytes))
+        refused = []
         fused = make_fused_wave_step(tel, num_vertices,
                                      interpret=interpret, donate=donate,
-                                     vmem_budget_bytes=budget)
+                                     vmem_budget_bytes=budget,
+                                     on_refuse=refused.append)
         if fused is not None:
             return fused
-    return _make_xla_step(tel, num_vertices, seg_pair=seg_pair,
+    step = _make_xla_step(tel, num_vertices, seg_pair=seg_pair,
                           seg_vert=seg_vert, donate=donate)
+    if use_kernel:
+        step.fallback = refused[0]
+    return step
 
 
 def tcd_wave(tel: DeviceTEL, alive: jnp.ndarray, ts, te, k, h,

@@ -5,8 +5,8 @@ window's live edges grouped by pair, its local pairs and vertices, the
 half-pair tables sorted by local vertex) and returns a jitted
 ``step(alive, ts, te, k, h) -> StepResult`` closure, or ``None`` when
 the tables exceed the kernel's SMEM capacity or its VMEM working set
-exceeds the budget — callers (``core.wave.make_wave_step_fn``) then use
-the XLA composite.
+exceeds the budget (``fused_step_refusal`` names which) — callers
+(``core.wave.make_wave_step_fn``) then use the XLA composite.
 
 Vertices with no live edge in the TEL never reach the kernel: their
 degree is 0 in every iteration, so they survive iff ``k <= 0`` and can
@@ -24,7 +24,7 @@ and out), which is the whole point vs the unfused chain's per-iteration
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,19 @@ def fused_step_vmem_bytes(num_pairs: int, num_vertices: int) -> int:
     v = _align(max(num_vertices, 1), 8)
     p = _align(max(num_pairs, 1), 8)
     return 4 * LANES * (4 * v + v + p + 4 * 8)
+
+
+def fused_step_refusal(num_edges: int, num_pairs: int, num_vertices: int,
+                       vmem_budget_bytes: int) -> Optional[str]:
+    """The budget a window's fused step would exceed: ``"smem_tables"``
+    (its index tables), ``"vmem_budget"`` (one program's working set), or
+    None when the kernel fits."""
+    if fused_step_smem_bytes(num_edges, num_pairs,
+                             num_vertices) > SMEM_TABLE_BUDGET:
+        return "smem_tables"
+    if fused_step_vmem_bytes(num_pairs, num_vertices) > vmem_budget_bytes:
+        return "vmem_budget"
+    return None
 
 
 def fused_step_cost(num_edges: int, num_pairs: int, num_vertices: int,
@@ -132,23 +145,27 @@ def _window_tables(tel):
 def make_fused_wave_step(tel, num_vertices: int, *,
                          interpret: Optional[bool] = None,
                          donate: bool = False,
-                         vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET):
+                         vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
+                         on_refuse: Optional[Callable[[str], None]] = None):
     """Build the fused Pallas step for one (capacity-shaped) DeviceTEL.
 
     Returns ``step(alive [W, V] bool, ts, te, k, h) -> StepResult`` (the
     ``core.wave`` result type, bit-identical to the composite), or
     ``None`` when the window's tables exceed the SMEM capacity or the
-    per-program VMEM working set exceeds the budget.  ``interpret=None``
-    auto-resolves: compiled on TPU, interpret mode elsewhere (the CPU
-    correctness gates).
+    per-program VMEM working set exceeds the budget; ``on_refuse`` is
+    then called with the budget's name (``fused_step_refusal``).
+    ``interpret=None`` auto-resolves: compiled on TPU, interpret mode
+    elsewhere (the CPU correctness gates).
     """
     interp = (not on_tpu()) if interpret is None else bool(interpret)
     v = int(num_vertices)
     verts, tables, n_e, n_p = _window_tables(tel)
     n_v = int(verts.size)
-    if not interp and (
-            fused_step_smem_bytes(n_e, n_p, n_v) > SMEM_TABLE_BUDGET
-            or fused_step_vmem_bytes(n_p, n_v) > int(vmem_budget_bytes)):
+    refusal = None if interp else fused_step_refusal(
+        n_e, n_p, n_v, int(vmem_budget_bytes))
+    if refusal is not None:
+        if on_refuse is not None:
+            on_refuse(refusal)
         return None
     v_pad = _align(max(n_v, 1), 8)
     pair_rows = _align(max(n_p, 1), 8)
