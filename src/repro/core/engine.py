@@ -239,7 +239,9 @@ class WavePipeline:
         per phase of each step; ``tcq.pipeline.sync`` around retire's
         blocking ``device_get``; ``tcq.engine.step_compile`` around the
         first call of a freshly built step function, where JAX traces,
-        lowers and compiles it (counted in ``pool_stats.step_compiles``).
+        lowers and compiles it (counted in ``pool_stats.step_compiles``)
+        — unless the step's ``program_warm`` says its size class's
+        program already ran (counted in ``step_program_reuses``).
         """
         with TraceAnnotation("tcq.pipeline.run_pool", pool=pool_stats.pool):
             self._run_ring(states, pool_stats, admit)
@@ -346,12 +348,18 @@ class WavePipeline:
                 slot.inflight = step(*args)
             else:
                 # the flag lives on the step object, which the engine pins
-                # per window-TEL entry: a cache hit's step is already built
-                with TraceAnnotation("tcq.engine.step_compile", pool=pool,
-                                     step=slot.step):
+                # per window-TEL entry: a cache hit's step is already built.
+                # A fresh fused step may share its size class's program.
+                warm = getattr(step, "program_warm", None)
+                if warm is not None and warm(*args):
                     slot.inflight = step(*args)
+                    pool_stats.step_program_reuses += 1
+                else:
+                    with TraceAnnotation("tcq.engine.step_compile",
+                                         pool=pool, step=slot.step):
+                        slot.inflight = step(*args)
+                    pool_stats.step_compiles += 1
                 step.called = True
-                pool_stats.step_compiles += 1
             slot.buf = slot.inflight.alive   # donated through; new handle
             pool_stats.device_steps += 1
             nonlocal occupied_total

@@ -427,9 +427,14 @@ class TCQEngine:
         paths), so compiled programs are shared across windows of similar
         size; the vertex-side segsum closure is capacity-shaped and
         always reused.  On the XLA degree path the pair-side closure is
-        reused too (it only fixes num_segments); the Pallas path rebuilds
-        it because its k_max band analysis depends on the windowed segment
-        ids.  The cache is LRU and keyed by ``(epoch, Ts, Te)``: a graph
+        reused too (it only fixes num_segments); the Pallas degree path
+        rebuilds it because its k_max band analysis depends on the
+        windowed segment ids, so a composite step over it compiles per
+        window.  The fused wave-peel step is built per window but
+        compiled per size class: its tables are padded to the class of
+        the window's live edges and passed as arguments, so every window
+        of a class shares one program.  The cache is LRU and keyed by
+        ``(epoch, Ts, Te)``: a graph
         update can never serve a stale truncation (new epoch, new key),
         while queries pinned to an older epoch — pass ``graph``/``epoch``
         explicitly — keep hitting their snapshot's entries.  Each entry
@@ -509,9 +514,10 @@ class TCQEngine:
             else:
                 seg_pair = aux.seg_pair_full
             # pin the fused (or composite) wave step per cache entry: the
-            # fused kernel's host-side band tables follow this truncation's
+            # fused kernel's host-side tables follow this truncation's
             # segment ids, so they are built once per (epoch, Ts, Te) and
-            # shared by every pipeline that peels this window
+            # shared by every pipeline that peels this window (the
+            # program they feed is shared by the window's size class)
             if self.mesh is not None:
                 plan = self._shard_plan
                 sharr = plan.window_arrays(g, int(Ts), int(Te))

@@ -41,9 +41,9 @@ class QueryStats:
     For queries served through ``TCQEngine.query_batch`` the pipeline is
     shared, so the device-side counters (device_steps, host_syncs,
     bytes_synced, peel_iters, lane_refills, occupancy, step_compiles,
-    wall_time_s) describe the whole batch and are reported identically on every
-    member query; schedule counters (cells_*, pruned_*, duplicates)
-    remain query-local.
+    step_program_reuses, wall_time_s) describe the whole batch and are
+    reported identically on every member query; schedule counters
+    (cells_*, pruned_*, duplicates) remain query-local.
     """
 
     n_timestamps: int = 0
@@ -73,6 +73,7 @@ class QueryStats:
     shard_occupancy: Optional[List[float]] = None  # per-lane-shard occupancy
     pool: int = -1                # service pool sequence number (-1: none)
     step_compiles: int = 0        # first calls of freshly built step fns
+    step_program_reuses: int = 0  # ... that reused a compiled program
 
     def absorb_pool(self, pool_stats: "QueryStats", *, window_edges: int,
                     batch_size: int) -> None:
@@ -92,6 +93,7 @@ class QueryStats:
         self.shard_occupancy = pool_stats.shard_occupancy
         self.pool = pool_stats.pool
         self.step_compiles = pool_stats.step_compiles
+        self.step_program_reuses = pool_stats.step_program_reuses
 
     @property
     def pruned_total(self) -> int:

@@ -498,9 +498,9 @@ class TCQService:
         The pool runs inside a ``tcq.service.pump`` profiler span (args
         ``pool``, its sequence number, and ``members`` at formation); its
         ``pool_log`` record carries ``pool``, ``window_tel_miss`` (0/1),
-        ``step_compiles`` and ``fallback`` (why a wanted fused kernel
-        gave way to the composite: "smem_tables" | "vmem_budget" |
-        "build_error", else None).
+        ``step_compiles``, ``step_program_reuses`` and ``fallback`` (why
+        a wanted fused kernel gave way to the composite: "smem_tables" |
+        "vmem_budget" | "build_error", else None).
         """
         if poll is not None:
             poll(self)
@@ -625,6 +625,7 @@ class TCQService:
             "fallback": getattr(wt.step_fn, "fallback", None),
             "window_tel_miss": int(self.engine._win_misses > misses),
             "step_compiles": pool_stats.step_compiles,
+            "step_program_reuses": pool_stats.step_program_reuses,
             "wall_s": done_s - t0,
         })
         if pool_stats.shard_occupancy is not None:

@@ -452,6 +452,13 @@ class DegradationLadder:
     def interpret(self) -> bool:
         return bool(getattr(self.rungs[self.rung][1], "interpret", False))
 
+    def program_warm(self, alive, ts, te, k, h) -> bool:
+        """Whether the current rung would reuse a compiled program: the
+        fused rung's size class already ran (its ``program_warm``); any
+        other rung, or a wrapped one, is taken to compile."""
+        warm = getattr(self.rungs[self.rung][1], "program_warm", None)
+        return bool(warm is not None and warm(alive, ts, te, k, h))
+
     def _demote(self, name: str, reason: str, detail: str = "") -> None:
         self._log(name, reason, detail)
         self.rung += 1

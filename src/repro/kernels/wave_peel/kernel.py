@@ -17,7 +17,9 @@ read from SMEM:
   * the wrapper (``ops.make_fused_wave_step``) compacts the window once
     on the host: live edges grouped by pair (the canonical sort), the
     window's local pairs, and their endpoints relabelled 0..V_loc-1;
-    the index tables ride in SMEM;
+    the index tables ride in SMEM, padded to the window's size class,
+    with the live pair and vertex counts beside them as the loop bounds;
+    slab rows past the live vertices enter as 0 and stay 0;
   * prologue: for every local pair, count its edges inside each lane's
     window and store ``ok[p] = count >= h`` (one row per pair);
   * fixpoint: a pair is active iff both endpoints are alive and
@@ -38,8 +40,6 @@ tests/test_kernels.py).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -53,8 +53,10 @@ _I32_MIN = jnp.iinfo(jnp.int32).min
 
 
 def _kernel(t_ref, pend_ref, pu_ref, pv_ref, vend_ref, hpo_ref, hpp_ref,
-            prm_ref, alive_ref, cur_ref, st_ref, ok_ref, deg_ref,
-            *, n_pairs: int, n_verts: int):
+            cnt_ref, prm_ref, alive_ref, cur_ref, st_ref, ok_ref, deg_ref):
+    # the window's live pair and vertex counts, read at run time: one
+    # compiled program serves every window of a size class
+    n_pairs, n_verts = cnt_ref[0], cnt_ref[1]
     ts, te = prm_ref[0:1, :], prm_ref[1:2, :]
     kk, hh = prm_ref[2:3, :], prm_ref[3:4, :]
     zero = jnp.zeros((1, LANES), jnp.int32)
@@ -124,14 +126,17 @@ def _kernel(t_ref, pend_ref, pu_ref, pv_ref, vend_ref, hpo_ref, hpp_ref,
     st_ref[4:STAT_ROWS, :] = jnp.zeros((STAT_ROWS - 4, LANES), jnp.int32)
 
 
-def wave_peel_pallas(tables, prm, alive, *, n_pairs: int, n_verts: int,
-                     pair_rows: int, interpret: bool):
+def wave_peel_pallas(tables, counts, prm, alive, *, pair_rows: int,
+                     interpret: bool):
     """Raw fused call over pre-padded arrays.
 
     tables: seven 1-D int32 SMEM tables (t, pair_end, pair_u, pair_v,
     vertex_end, hp_other, hp_pair) — see ``ops.make_fused_wave_step``;
-    prm: [8, W_pad] int32 rows ts, te, k, h; alive: [V_pad, W_pad] int32
-    0/1 lane slab (V_pad a multiple of 8, W_pad of ``LANES``).
+    counts: [2] int32 SMEM (live local pairs, live local vertices), the
+    loop bounds; prm: [8, W_pad] int32 rows ts, te, k, h; alive:
+    [V_pad, W_pad] int32 0/1 lane slab (V_pad a multiple of 8, W_pad of
+    ``LANES``), whose rows past the live vertices are 0.  ``pair_rows``
+    (a multiple of 8, at least the live pairs) sizes the pair-ok scratch.
 
     Returns (alive [V_pad, W_pad] int32, stats [8, W_pad] int32 with rows
     n_edges, tti_lo, tti_hi, iters).
@@ -141,9 +146,9 @@ def wave_peel_pallas(tables, prm, alive, *, n_pairs: int, n_verts: int,
     slab = pl.BlockSpec((v_pad, LANES), lambda q: (0, q))
     stat = pl.BlockSpec((STAT_ROWS, LANES), lambda q: (0, q))
     return pl.pallas_call(
-        functools.partial(_kernel, n_pairs=n_pairs, n_verts=n_verts),
+        _kernel,
         grid=(w_pad // LANES,),
-        in_specs=[smem] * len(tables) + [stat, slab],
+        in_specs=[smem] * (len(tables) + 1) + [stat, slab],
         out_specs=[slab, stat],
         out_shape=[jax.ShapeDtypeStruct((v_pad, w_pad), jnp.int32),
                    jax.ShapeDtypeStruct((STAT_ROWS, w_pad), jnp.int32)],
@@ -153,4 +158,4 @@ def wave_peel_pallas(tables, prm, alive, *, n_pairs: int, n_verts: int,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         name="wave_peel",
-    )(*tables, prm, alive)
+    )(*tables, counts, prm, alive)

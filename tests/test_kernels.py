@@ -169,7 +169,7 @@ def _random_temporal_graph(rng):
 
 def _fuzz_fused_vs_composite(seed, *, capacity_padding, max_wave=12):
     from repro.core.graph import pow2_capacity
-    from repro.core.wave import make_wave_step_fn, unpack_alive_u32
+    from repro.core.wave import make_wave_step_fn
 
     rng = np.random.default_rng(seed)
     g, tmax = _random_temporal_graph(rng)
@@ -202,11 +202,16 @@ def _fuzz_fused_vs_composite(seed, *, capacity_padding, max_wave=12):
 
     args = (alive, jnp.asarray(ts), jnp.asarray(te),
             jnp.asarray(k), jnp.asarray(h))
-    rf, rc = fused(*args), comp(*args)
+    _assert_matches_composite(fused(*args), comp(*args), nv, f"seed={seed}")
+
+
+def _assert_matches_composite(rf, rc, nv, what):
+    from repro.core.wave import unpack_alive_u32
+
     for field in ("alive", "packed", "tti_lo", "tti_hi", "n_edges", "iters"):
         np.testing.assert_array_equal(
             np.asarray(getattr(rf, field)), np.asarray(getattr(rc, field)),
-            err_msg=f"fused vs composite diverge on {field} (seed={seed})")
+            err_msg=f"fused vs composite diverge on {field} ({what})")
     assert np.asarray(rf.packed).dtype == np.uint32
     np.testing.assert_array_equal(
         unpack_alive_u32(np.asarray(rf.packed), nv), np.asarray(rf.alive))
@@ -257,6 +262,166 @@ def test_fused_step_through_tcd_wave():
     for field in ("alive", "tti_lo", "tti_hi", "n_edges", "n_verts", "iters"):
         np.testing.assert_array_equal(np.asarray(getattr(got, field)),
                                       np.asarray(getattr(ref, field)))
+
+
+CLASS_V = 160          # vertex width of the size-class windows below
+CLASS_T = 48           # their timestamps
+
+
+def _class_window(kind, seed):
+    """A window TEL of the fused step's smallest size class (128 edge
+    rows) over ``CLASS_V`` vertices: ``matching`` has more local vertices
+    than live edges (every pair one edge, no shared endpoint),
+    ``pow2_edges`` exactly 128 live edges, ``random`` 40-120."""
+    from repro.core.graph import TemporalGraph
+
+    rng = np.random.default_rng(seed)
+    if kind == "matching":
+        n = int(rng.integers(30, 60))
+        ends = rng.permutation(CLASS_V)[:2 * n]
+        u, w = ends[:n], ends[n:]
+    else:
+        n = 128 if kind == "pow2_edges" else int(rng.integers(40, 120))
+        u = rng.integers(0, CLASS_V, 4 * n)
+        w = (u + rng.integers(1, CLASS_V, 4 * n)) % CLASS_V
+    t = rng.integers(0, CLASS_T, u.size)
+    # distinct (u, w, t) edges, so that exactly n stay live
+    _, first = np.unique(np.stack([u, w, t]), axis=1, return_index=True)
+    keep = np.sort(first)[:n]
+    g = TemporalGraph.from_edges(u[keep], w[keep], t[keep],
+                                 num_vertices=CLASS_V)
+    assert g.num_edges == n
+    return g.device_tel()
+
+
+def _lane_args(seed, wave):
+    """Host-built step arguments (building them runs no JAX program)."""
+    rng = np.random.default_rng(seed)
+    ts = rng.integers(0, CLASS_T, wave).astype(np.int32)
+    te = (ts + rng.integers(0, CLASS_T, wave)).astype(np.int32)
+    k = rng.integers(1, 4, wave).astype(np.int32)
+    h = rng.integers(1, 3, wave).astype(np.int32)
+    alive = rng.random((wave, CLASS_V)) < 0.9
+    return tuple(jnp.asarray(a) for a in (alive, ts, te, k, h))
+
+
+@pytest.mark.kernel_gate
+@pytest.mark.parametrize("kind,wave", [("matching", 8), ("pow2_edges", 8),
+                                       ("random", 200)])
+def test_fused_step_shares_one_program_per_size_class(kind, wave):
+    """Every window of a size class runs the class's one compiled
+    program: after a first window of the class, a new window's step
+    builds and runs with no backend compile, and still equals the
+    composite on every field (W = 200 spans two lane tiles)."""
+    import jax
+    import jax.monitoring
+
+    from repro.core.wave import make_wave_step_fn
+    from repro.kernels.wave_peel.ops import make_fused_wave_step
+
+    first = make_fused_wave_step(_class_window("random", 7), CLASS_V,
+                                 interpret=True)
+    jax.block_until_ready(first(*_lane_args(8, wave)))
+    tel = _class_window(kind, 9)
+    args = _lane_args(10, wave)
+    compiles = []
+
+    def listen(event, start, end, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_time_span_listener(listen)
+    try:
+        fused = make_fused_wave_step(tel, CLASS_V, interpret=True)
+        assert fused.program_warm(*args)
+        rf = jax.block_until_ready(fused(*args))
+        # restarted from its own fixpoint, the peel stops after one
+        # iteration: the class's padding rows never move
+        again = (rf.alive,) + args[1:]
+        rf2 = jax.block_until_ready(fused(*again))
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(listen)
+    assert compiles == []
+    assert int(rf2.iters) == 1
+    assert fused.operand_shapes == first.operand_shapes
+    assert fused.operand_shapes[0] == (128,)
+    n_e, _, n_v = fused.local_counts
+    if kind == "matching":
+        assert n_v > n_e
+    if kind == "pow2_edges":
+        assert n_e == 128
+    comp = make_wave_step_fn(tel, CLASS_V, use_kernel=False)
+    _assert_matches_composite(rf, comp(*args), CLASS_V, kind)
+    _assert_matches_composite(rf2, comp(*again), CLASS_V, kind + ", again")
+
+
+def test_fused_step_class_over_smem_budget_takes_exact_sizes(monkeypatch):
+    """A window whose size class would exceed the SMEM table budget while
+    its exact sizes fit keeps the kernel, on tables of its exact sizes."""
+    import repro.kernels.wave_peel.ops as ops
+    from repro.core.wave import make_wave_step_fn
+
+    tel = _class_window("random", 11)
+    verts, _, n_e, n_p = ops._window_tables(tel)
+    exact = ops.fused_step_smem_bytes(n_e, n_p, verts.size)
+    assert ops.fused_step_smem_bytes(128, 128, CLASS_V) > exact
+    monkeypatch.setattr(ops, "SMEM_TABLE_BUDGET", exact)
+    fused = ops.make_fused_wave_step(tel, CLASS_V, interpret=True)
+    assert fused.operand_shapes == [(n_e,), (n_p,), (n_p,), (n_p,),
+                                    (verts.size,), (2 * n_p,), (2 * n_p,)]
+    args = _lane_args(12, 8)
+    comp = make_wave_step_fn(tel, CLASS_V, use_kernel=False)
+    _assert_matches_composite(fused(*args), comp(*args), CLASS_V, "exact")
+
+
+def test_fused_step_class_over_vmem_budget_buckets_pairs_and_vertices():
+    """A window whose edge-keyed class (2 e vertex rows) would exceed the
+    VMEM budget takes power-of-two rows of its own pairs and vertices,
+    and still equals the composite."""
+    import repro.kernels.wave_peel.ops as ops
+    from repro.core.graph import pow2_capacity
+    from repro.core.wave import make_wave_step_fn
+
+    tel = _class_window("matching", 13)      # at most 120 vertices
+    verts, _, n_e, n_p = ops._window_tables(tel)
+    p_cap = pow2_capacity(n_p)
+    v_cap = min(pow2_capacity(verts.size), CLASS_V)
+    budget = ops.fused_step_vmem_bytes(p_cap, v_cap)
+    assert ops.fused_step_vmem_bytes(128, CLASS_V) > budget
+    assert ops.fused_step_class(n_e, n_p, verts.size, CLASS_V, budget) == \
+        (128, p_cap, v_cap)
+    fused = ops.make_fused_wave_step(tel, CLASS_V, interpret=True,
+                                     vmem_budget_bytes=budget)
+    assert fused.operand_shapes[1] == (p_cap,)
+    assert fused.operand_shapes[4] == (v_cap,)
+    args = _lane_args(14, 8)
+    comp = make_wave_step_fn(tel, CLASS_V, use_kernel=False)
+    _assert_matches_composite(fused(*args), comp(*args), CLASS_V, "tier 2")
+
+
+def test_fused_program_warm_follows_jax_cache():
+    """``program_warm`` turns true once the class's program has run and
+    false again when JAX's caches are cleared, so a recompile is never
+    counted as a reuse."""
+    import jax
+
+    from repro.kernels.wave_peel.ops import make_fused_wave_step
+
+    args = _lane_args(15, 8)
+    first = make_fused_wave_step(_class_window("random", 16), CLASS_V,
+                                 interpret=True)
+    jax.block_until_ready(first(*args))
+    second = make_fused_wave_step(_class_window("random", 17), CLASS_V,
+                                  interpret=True)
+    assert second.program_warm(*args)
+    # another lane count is another program
+    assert not second.program_warm(*_lane_args(15, 16))
+    jax.clear_caches()
+    # a recompile of another program after the clear leaves this one cold
+    jax.block_until_ready(second(*_lane_args(15, 16)))
+    assert not second.program_warm(*args)
+    jax.block_until_ready(second(*args))
+    assert second.program_warm(*args)
 
 
 def test_fused_vmem_budget_falls_back_to_composite():

@@ -76,12 +76,30 @@ def test_fused_wave_peel_step_compiles(one_chip):
     assert 8_000 <= tel.num_edges <= 32_000
     step = make_fused_wave_step(tel, V_SMOKE, interpret=False)
     assert step is not None and step.interpret is False
-    w = 64
-    lane = _sds((w,), jnp.int32, one_chip)
-    compiled = jax.jit(step.__wrapped__).lower(
-        _sds((w, V_SMOKE), jnp.bool_, one_chip),
-        lane, lane, lane, lane).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in _compile_fused(step, one_chip)
+
+
+def test_fused_wave_peel_class_step_compiles(one_chip):
+    """A MathOverflow-sized window takes its power-of-two size class:
+    the program that every window of the class shares compiles."""
+    from repro.graphs import powerlaw_temporal
+    from repro.kernels.wave_peel.ops import make_fused_wave_step
+
+    tel = powerlaw_temporal(V_SMOKE, 800, 64, seed=1).device_tel()
+    step = make_fused_wave_step(tel, V_SMOKE, interpret=False)
+    assert step is not None and step.operand_shapes[0] == (1024,)
+    assert "tpu_custom_call" in _compile_fused(step, one_chip)
+
+
+def _compile_fused(step, sharding, w=64):
+    """HLO text of the fused step's shared program, compiled for the
+    described chip with this window's arguments."""
+    window = jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding),
+                          step.window_args)
+    lane = _sds((w,), jnp.int32, sharding)
+    return step.jitted.lower(
+        *window, _sds((w, V_SMOKE), jnp.bool_, sharding),
+        lane, lane, lane, lane, interpret=False).compile().as_text()
 
 
 def test_banded_segsum_kernel_compiles(one_chip):

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.core import TCQService, TemporalGraph
+from repro.core import ResilienceConfig, TCQService, TemporalGraph
 
 SPANS = ("tcq.service.pump", "tcq.service.finalize",
          "tcq.engine.window_tel", "tcq.engine.step_compile",
@@ -149,3 +149,44 @@ def test_each_fallback_reason_is_named(monkeypatch, reason):
     assert rec["backend"] == "xla" and rec["fallback"] == reason
     assert tk.result.by_tti().keys() == want.result.by_tti().keys()
     assert ref.pool_log[0]["fallback"] is None
+
+
+def test_second_window_of_a_class_reuses_the_fused_program(tmp_path):
+    _second_window_reuses_its_class(tmp_path, None)
+
+
+def test_second_window_of_a_class_reuses_the_ladder_program(tmp_path):
+    _second_window_reuses_its_class(tmp_path, ResilienceConfig())
+
+
+def _second_window_reuses_its_class(tmp_path, resilience):
+    """Two pools on different windows of one fused-step size class: the
+    second pool's fresh step (the fused step, or a degradation ladder on
+    its fused rung) reuses the class's compiled program, so it counts a
+    reuse, no compile, and opens no ``step_compile`` span; its answers
+    equal the composite's."""
+    svc = TCQService(small_graph(), use_kernel=True, cache=False,
+                     resilience=resilience)
+    reqs = [{"k": 2, "ts": ts, "te": ts + 12} for ts in (0, 24)]
+    tickets = []
+    with jax.profiler.trace(str(tmp_path)):
+        for req in reqs:
+            tickets.append(svc.submit(req))
+            svc.run_until_idle()
+    ref = TCQService(small_graph(), cache=False)
+    for req, tk in zip(reqs, tickets):
+        want = ref.submit(req)
+        ref.run_until_idle()
+        assert tk.result.by_tti().keys() == want.result.by_tti().keys()
+    first, second = svc.pool_log
+    if resilience is not None:
+        assert svc.engine.resilience_events() == []   # stayed on its rung
+    assert first["backend"] == second["backend"] == "pallas"
+    assert first["window_tel_miss"] == second["window_tel_miss"] == 1
+    assert first["step_compiles"] + first["step_program_reuses"] == 1
+    assert second["step_compiles"] == 0
+    assert second["step_program_reuses"] == 1
+    compiled = [s for s in spans_of(str(tmp_path))
+                if s[0] == "tcq.engine.step_compile"]
+    assert [s[3]["pool"] for s in compiled] == \
+        [first["pool"]] * first["step_compiles"]
