@@ -73,7 +73,7 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
     they describe the lowerings, not the host — which is why they (and
     not interpret-mode wall-clock) are the regression gate.
     """
-    from repro.core.wave import _wave_step_nodonate, make_wave_step_fn
+    from repro.core.wave import make_wave_step_fn
     from repro.kernels.segdeg.ops import on_tpu
     from repro.kernels.wave_peel.ops import fused_step_cost
     from repro.launch.hlo_cost import HLOCost
@@ -83,10 +83,8 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
     v = g.num_vertices
     e = int(tel.t.shape[0])
     p = int(tel.pair_u.shape[0])
-    sp, sv = make_segsum_fns(g, use_kernel=False)
     fused = make_wave_step_fn(tel, v, use_kernel=True)
-    comp = make_wave_step_fn(tel, v, use_kernel=False,
-                             seg_pair=sp, seg_vert=sv)
+    comp = make_wave_step_fn(tel, v, use_kernel=False)
 
     rng = np.random.default_rng(seed)
     uts = g.unique_ts
@@ -119,12 +117,12 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
                 f"on {field} (graph={name}, seed={seed})")
     iters = int(rf.iters)
 
-    # unfused chain: compiled HLO, while-body per-iteration accounting
-    # (the dynamic fixpoint cond has no static trip count, so the module
-    # total counts the body once; N iterations add (N-1) x body)
-    hlo = _wave_step_nodonate.lower(
-        tel, alive, ts, te, k, h, num_vertices=v,
-        seg_pair=sp, seg_vert=sv).compile().as_text()
+    # unfused chain (the served XLA composite, its shared class program
+    # over this window's tables): compiled HLO, while-body per-iteration
+    # accounting (the dynamic fixpoint cond has no static trip count, so
+    # the module total counts the body once; N iterations add (N-1) x body)
+    hlo = comp.jitted.lower(*comp.window_args, alive, ts, te, k,
+                            h).compile().as_text()
     hc = HLOCost(hlo)
     # only dynamic-condition loops (the fixpoint) scale with iters; their
     # bodies already fold in any nested counted loops (scatter lowerings)
@@ -133,9 +131,11 @@ def analyze_fused_step(name: str = "collegemsg", wave: int = 16,
     bytes_it = sum(b["bytes"] for b in bodies)
     unfused_bytes = hc.bytes + (iters - 1) * bytes_it
     unfused_flops = hc.flops + (iters - 1) * flops_it
-    # [W, E] / [E, W] HBM materializations per iteration in the unfused
-    # lowering (edge activity + its transposed f32 segsum operand)
-    we_census = hc.shape_census((wave, e)) + hc.shape_census((e, wave))
+    # [W, E] / [E, W] HBM materializations in the unfused lowering (the
+    # lanes' window and edge-activity masks over the class's edge rows)
+    e_rows = comp.rows[0]
+    we_census = (hc.shape_census((wave, e_rows))
+                 + hc.shape_census((e_rows, wave)))
 
     fc = fused_step_cost(*fused.local_counts, wave=wave, iters=iters)
     # structural [W, E] check on the fused side: the kernel's only HBM

@@ -748,8 +748,7 @@ class ShardedWavePipeline(WavePipeline):
         if wave % L:
             raise ValueError(
                 f"wave={wave} not a multiple of lane shards {L}")
-        super().__init__(None, num_vertices, None, None, wave, depth,
-                         step_fn=step_fn)
+        super().__init__(None, num_vertices, wave, depth, step_fn=step_fn)
         self.mesh = mesh
         self.lane_shards = L
         self.model_shards = m

@@ -45,10 +45,6 @@ Device mechanics (carried over from the single-query pipeline, measured
   worst re-induces a core its query already found, and such duplicates
   are removed by TTI identity (Property 2) and counted per query.
 
-* **Kernel degree path** — the Pallas ``banded_segsum`` closures (and
-  their k_max band analysis) are built once per ``TCQEngine`` by the
-  dispatching wrapper: compiled Pallas on TPU, XLA segment-sum elsewhere.
-
 The pipeline peels against a *windowed* TEL (``TCQEngine._window_tel``,
 epoch-keyed so graph updates can never serve stale truncations): for a
 pool, one TEL truncated to the union window serves every lane — per-lane
@@ -133,24 +129,19 @@ class WavePipeline:
     ``TCQEngine.query(mode="wave")``.
     """
 
-    def __init__(self, tel: DeviceTEL, num_vertices: int,
-                 seg_pair, seg_vert, wave: int, depth: int = 2,
-                 step_fn=None):
+    def __init__(self, tel: DeviceTEL, num_vertices: int, wave: int,
+                 depth: int = 2, step_fn=None):
         self.tel = tel
         self.num_vertices = num_vertices
-        self.seg_pair = seg_pair
-        self.seg_vert = seg_vert
         self.wave = wave
         self.depth = max(1, int(depth))
         # the device step: a prebuilt ``make_wave_step_fn`` closure (the
-        # engine pins one per windowed TEL so the fused kernel's host-side
-        # band analysis is never rebuilt per pipeline), else the default
+        # engine pins one per windowed TEL so its host-side window tables
+        # are never rebuilt per pipeline), else the default
         # dispatch — fused Pallas on TPU, XLA composite elsewhere.  The
         # lane buffer is donated through every step either way.
         if step_fn is None:
-            step_fn = make_wave_step_fn(tel, num_vertices,
-                                        seg_pair=seg_pair, seg_vert=seg_vert,
-                                        donate=True)
+            step_fn = make_wave_step_fn(tel, num_vertices, donate=True)
         self._step = step_fn
 
     # ------------------------------------------------- subclass seams

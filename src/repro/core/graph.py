@@ -131,6 +131,58 @@ def pow2_capacity(n: int, floor: int = 128) -> int:
     return max(floor, 1 << max(0, int(n) - 1).bit_length())
 
 
+class WindowTables(NamedTuple):
+    """A window's live edges, pairs and vertices in local ids (host
+    numpy): ``verts`` are the global ids of the local vertices (sorted);
+    ``tables`` the seven 1-D int32 tables at their live lengths — ``t``
+    (``n_edges``, grouped by pair), ``pair_end``, ``pair_u``, ``pair_v``
+    (``n_pairs``; ``pair_end`` is one past each pair's last edge),
+    ``vertex_end`` (``verts.size``; one past each vertex's last
+    half-pair), ``hp_other``, ``hp_pair`` (``2 * n_pairs``, half-pairs
+    sorted by local vertex: the other end and the pair)."""
+
+    verts: np.ndarray
+    tables: tuple
+    n_edges: int
+    n_pairs: int
+
+    @property
+    def n_vertices(self) -> int:
+        return int(self.verts.size)
+
+
+def window_tables(tel: "DeviceTEL") -> WindowTables:
+    """Host-side compaction of a (possibly padded) TEL to its live edges:
+    the one table build that the fused kernel's and the XLA composite's
+    step share.  ``tel``'s arrays may be host or device arrays; only its
+    edges and its pair count are read."""
+    num_pairs = int(tel.pair_u.shape[0])
+    pair_id = np.asarray(tel.pair_id)
+    live = pair_id < num_pairs          # sentinel edges carry pair_id == P
+    pid = pair_id[live]
+    t = np.asarray(tel.t)[live]
+    src = np.asarray(tel.src)[live]
+    dst = np.asarray(tel.dst)[live]
+    if np.any(pid[1:] < pid[:-1]):
+        raise ValueError("TEL edges are not grouped by pair_id")
+    first = np.ones(pid.size, dtype=bool)
+    first[1:] = pid[1:] != pid[:-1]
+    starts = np.flatnonzero(first)
+    pair_end = np.append(starts[1:], pid.size)
+    verts = np.unique(np.concatenate([src[first], dst[first]]))
+    pu = np.searchsorted(verts, src[first])
+    pv = np.searchsorted(verts, dst[first])
+    n_pairs = int(starts.size)
+    hp_vert = np.concatenate([pu, pv])
+    order = np.argsort(hp_vert, kind="stable")
+    hp_other = np.concatenate([pv, pu])[order]
+    hp_pair = np.concatenate([np.arange(n_pairs)] * 2)[order]
+    vert_end = np.searchsorted(hp_vert[order], np.arange(verts.size),
+                               side="right")
+    tables = (t, pair_end, pu, pv, vert_end, hp_other, hp_pair)
+    return WindowTables(verts, tables, int(pid.size), n_pairs)
+
+
 def _merge_sorted_unique(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Union of two sorted-unique int arrays in O(|a| + |b| log |a|)."""
     if b.size == 0:

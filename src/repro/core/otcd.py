@@ -23,8 +23,7 @@ Two execution modes share that schedule:
   TTI + stats + uint32 bitmask pack) per batch of schedule cells with
   per-lane (ts, te, k, h), packed O(W·V/32) result transfer with deferred
   bulk decode, and a depth-D slot ring so host pruning bookkeeping
-  overlaps device compute.  The Pallas ``banded_segsum`` degree closures
-  are built once per engine (epoch).
+  overlaps device compute.
 
 (The seed stepwise engine — one blocking host round-trip per step — served
 as the pipeline's benchmark baseline through PR 2 and was retired once the
@@ -38,7 +37,7 @@ device TEL inside power-of-two *capacity classes* — edge/pair/vertex
 buffers are sentinel-padded to capacities that only grow by doubling, so
 a streaming append almost never changes a compiled program's shapes.
 ``_window_tel`` is keyed by ``(epoch, Ts, Te)`` and each cache entry pins
-the TEL *and* the degree closures it was built with, so a graph update
+the TEL *and* the step it was built with, so a graph update
 can never serve a stale truncation to a new query nor a fresh truncation
 to a query pinned to an older epoch (snapshot consistency — the contract
 ``core/service.py``'s mid-flight admission is built on).
@@ -62,7 +61,8 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import tcd as tcd_mod
 from repro.core.engine import WavePipeline
-from repro.core.graph import DeviceTEL, TemporalGraph, pow2_capacity
+from repro.core.graph import (DeviceTEL, TemporalGraph, pow2_capacity,
+                              window_tables)
 from repro.core.intervals import IntervalSet
 from repro.core.results import CoreResult, QueryStats, TCQResult
 from repro.core.scheduler import QueryState, autotune_wave
@@ -76,29 +76,25 @@ _EPOCH_AUX_MAX = 8          # snapshot pair-table LRU (epochs still in flight)
 class WindowTEL(NamedTuple):
     """One window-truncated TEL plus everything needed to peel it.
 
-    The degree closures and the device vertex width are *pinned per
-    entry*: they were built against this entry's capacity classes, so a
-    later capacity growth (or epoch bump) can never mix a cached TEL
-    with incompatible closures.
+    The step and the device vertex width are *pinned per entry*: they
+    were built against this entry's capacity classes, so a later
+    capacity growth (or epoch bump) can never mix a cached TEL with an
+    incompatible step.
     """
 
     tel: DeviceTEL
-    seg_pair: object         # edge->pair segsum closure for this TEL
-    seg_vert: object         # halfpair->vertex segsum closure
     num_vertices: int        # device vertex width (capacity, >= live V)
     window_edges: int        # live (non-sentinel) edges inside the window
     step_fn: object = None   # pinned wave step (make_wave_step_fn closure)
 
 
 class _EpochAux(NamedTuple):
-    """Per-epoch pair-table device arrays + closures (capacity padded)."""
+    """Per-epoch pair-table device arrays (capacity padded)."""
 
     pair_u: object
     pair_v: object
     hp_src: object
     hp_pair: object
-    seg_pair_full: object    # full-graph pair closure (XLA path reuse)
-    seg_vert: object
     pair_cap: int
     v_cap: int
 
@@ -107,12 +103,10 @@ class TCQEngine:
     """Holds the device TEL + compiled TCD programs for one temporal graph.
 
     ``use_kernel`` selects the device step for wave mode: True forces
-    the Pallas paths — the fused peel-to-fixpoint wave kernel
-    (``kernels/wave_peel``) plus the banded segsum closures (interpret
-    mode off-TPU) — False the XLA composite / segment-sum reference,
-    None (default) auto-dispatches.  The closures — including the
-    kernels' host-side band analyses — are built once per engine epoch
-    (full TEL) or per cached window truncation and reused by every wave
+    the fused peel-to-fixpoint wave kernel (``kernels/wave_peel``;
+    interpret mode off-TPU), False the XLA composite, None (default)
+    auto-dispatches.  The step — with its host-side window tables — is
+    built once per cached window truncation and reused by every wave
     query on this engine.
 
     The engine is streaming-capable: :meth:`update_graph` installs a new
@@ -192,8 +186,6 @@ class TCQEngine:
         power of two, so recompiles are amortized O(1) over a stream and
         shapes are shared across epochs in the same capacity class.
         """
-        from repro.kernels.segdeg.ops import make_banded_segsum
-
         if initial:
             self._edge_cap = graph.num_edges
             self._pair_cap = graph.num_pairs
@@ -226,16 +218,8 @@ class TCQEngine:
         if initial or grew_verts:
             self.num_vertices = self._v_cap
             self._ones = jnp.ones((self._v_cap,), dtype=bool)
-        # closures are capacity-shaped but id-dependent (the Pallas band
-        # analysis follows the segment ids), so they refresh per epoch;
-        # the XLA path's partials are free to rebuild
-        self._seg_pair = make_banded_segsum(
-            arrs["pair_id"], self._pair_cap, use_kernel=self._use_kernel)
-        self._seg_vert = make_banded_segsum(
-            arrs["hp_src"], self._v_cap, use_kernel=self._use_kernel)
         aux = _EpochAux(self.tel.pair_u, self.tel.pair_v, self.tel.hp_src,
-                        self.tel.hp_pair, self._seg_pair, self._seg_vert,
-                        self._pair_cap, self._v_cap)
+                        self.tel.hp_pair, self._pair_cap, self._v_cap)
         self._remember_aux(self.epoch, aux)
         if self.mesh is not None:
             self._install_shards(graph, initial)
@@ -333,11 +317,9 @@ class TCQEngine:
             self._epoch_aux.popitem(last=False)
 
     def _aux_for(self, epoch: int, g: TemporalGraph) -> _EpochAux:
-        """Pair-table device arrays + closures for one epoch's snapshot,
-        padded to the engine's *current* capacity classes (snapshots are
-        ancestors of the current graph, so they always fit)."""
-        from repro.kernels.segdeg.ops import make_banded_segsum
-
+        """Pair-table device arrays for one epoch's snapshot, padded to
+        the engine's *current* capacity classes (snapshots are ancestors
+        of the current graph, so they always fit)."""
         hit = self._epoch_aux.get(epoch)
         if hit is not None:
             self._epoch_aux.move_to_end(epoch)
@@ -351,10 +333,6 @@ class TCQEngine:
         aux = _EpochAux(
             jnp.asarray(arrs["pair_u"]), jnp.asarray(arrs["pair_v"]),
             jnp.asarray(arrs["hp_src"]), jnp.asarray(arrs["hp_pair"]),
-            make_banded_segsum(arrs["pair_id"], self._pair_cap,
-                               use_kernel=self._use_kernel),
-            make_banded_segsum(arrs["hp_src"], self._v_cap,
-                               use_kernel=self._use_kernel),
             self._pair_cap, self._v_cap)
         self._remember_aux(epoch, aux)
         return aux
@@ -421,19 +399,15 @@ class TCQEngine:
 
         Every cell of a query's schedule lies inside [Ts, Te], so both the
         serial engine and the wave pipeline peel against only the window's
-        edges — per-iteration work scales with the window, not |E|.  Edge
-        arrays are padded to a power-of-two bucket with sentinel edges
-        (t=int32 min, pair_id=pair capacity, ignored by both degree
-        paths), so compiled programs are shared across windows of similar
-        size; the vertex-side segsum closure is capacity-shaped and
-        always reused.  On the XLA degree path the pair-side closure is
-        reused too (it only fixes num_segments); the Pallas degree path
-        rebuilds it because its k_max band analysis depends on the
-        windowed segment ids, so a composite step over it compiles per
-        window.  The fused wave-peel step is built per window but
-        compiled per size class: its tables are padded to the class of
-        the window's live edges and passed as arguments, so every window
-        of a class shares one program.  The cache is LRU and keyed by
+        edges — per-iteration work scales with the window, not |E|.  The
+        serial engine reads the truncated TEL, whose edge arrays are
+        padded to a power-of-two bucket with sentinel edges (t=int32 min,
+        pair_id=pair capacity).  The wave step reads the window's own
+        tables (``window_tables``: local edges, pairs, half-pairs and
+        vertices), built once per entry; both of its lowerings, the fused
+        kernel and the XLA composite, pad them to the window's size class
+        and pass them as arguments, so every window of a class shares one
+        compiled program.  The cache is LRU and keyed by
         ``(epoch, Ts, Te)``: a graph
         update can never serve a stale truncation (new epoch, new key),
         while queries pinned to an older epoch — pass ``graph``/``epoch``
@@ -452,7 +426,7 @@ class TCQEngine:
             return hit
         self._win_misses += 1
         with TraceAnnotation("tcq.engine.window_tel", pool=pool):
-            out = self._build_window_tel(g, ep, int(Ts), int(Te))
+            out = self._build_window_tel(g, ep, int(Ts), int(Te), pool)
         if len(self._win_cache) >= _WINDOW_CACHE_MAX:
             self._win_cache.popitem(last=False)     # evict least-recent
             self._win_evictions += 1
@@ -460,9 +434,10 @@ class TCQEngine:
         return out
 
     def _build_window_tel(self, g: TemporalGraph, ep: int, Ts: int,
-                          Te: int) -> WindowTEL:
+                          Te: int, pool: int = -1) -> WindowTEL:
         """The cache-miss path of :meth:`_window_tel`: truncation, sort,
-        uploads and the pinned step's build."""
+        uploads, the window tables (inside a ``tcq.engine.window_tables``
+        span, args ``pool`` and ``edges``) and the pinned step's build."""
         from repro.core.wave import make_wave_step_fn
 
         aux = self._aux_for(ep, g)
@@ -470,18 +445,7 @@ class TCQEngine:
         e = int(idx.size)
         donate = self._resilience is None
         if ep == self.epoch and e >= g.num_edges:
-            if self.mesh is not None:
-                step = self._sharded_step(self._plan_arrays, self.tel,
-                                          Ts, Te, full=True)
-            else:
-                step = make_wave_step_fn(self.tel, self._v_cap,
-                                         seg_pair=self._seg_pair,
-                                         seg_vert=self._seg_vert,
-                                         use_kernel=self._use_kernel,
-                                         donate=donate,
-                                         resilience=self._resilience)
-            return WindowTEL(self.tel, self._seg_pair, self._seg_vert,
-                             self._v_cap, e, step)
+            tel = host = self.tel
         else:
             bucket = pow2_capacity(e)
             pad = bucket - e
@@ -490,49 +454,43 @@ class TCQEngine:
             # sentinel pair id = pair capacity (dropped by the scatter)
             t_w = np.concatenate(
                 [g.t[idx], np.full(pad, _I32_MIN, np.int32)])
-            pid_w = np.concatenate(
-                [g.pair_id[idx], np.full(pad, aux.pair_cap, np.int32)])
-            tel = DeviceTEL(
-                src=jnp.asarray(np.concatenate(
-                    [g.src[idx], np.zeros(pad, np.int32)])),
-                dst=jnp.asarray(np.concatenate(
-                    [g.dst[idx], np.zeros(pad, np.int32)])),
-                t=jnp.asarray(t_w),
-                pair_id=jnp.asarray(pid_w),
+            host = DeviceTEL(
+                src=np.concatenate([g.src[idx], np.zeros(pad, np.int32)]),
+                dst=np.concatenate([g.dst[idx], np.zeros(pad, np.int32)]),
+                t=t_w,
+                pair_id=np.concatenate(
+                    [g.pair_id[idx], np.full(pad, aux.pair_cap, np.int32)]),
                 pair_u=aux.pair_u,
                 pair_v=aux.pair_v,
                 hp_src=aux.hp_src,
                 hp_pair=aux.hp_pair,
-                time_perm=jnp.asarray(
-                    np.argsort(t_w, kind="stable").astype(np.int32)),
+                time_perm=np.argsort(t_w, kind="stable").astype(np.int32),
             )
-            if self._use_kernel:
-                from repro.kernels.segdeg.ops import make_banded_segsum
-
-                seg_pair = make_banded_segsum(pid_w, aux.pair_cap,
-                                              use_kernel=True)
+            tel = DeviceTEL(*(jnp.asarray(a) for a in host))
+        if self.mesh is not None:
+            if tel is self.tel:
+                arrays = self._plan_arrays
             else:
-                seg_pair = aux.seg_pair_full
-            # pin the fused (or composite) wave step per cache entry: the
-            # fused kernel's host-side tables follow this truncation's
-            # segment ids, so they are built once per (epoch, Ts, Te) and
-            # shared by every pipeline that peels this window (the
-            # program they feed is shared by the window's size class)
-            if self.mesh is not None:
                 plan = self._shard_plan
                 sharr = plan.window_arrays(g, int(Ts), int(Te))
                 hp = plan.hp_arrays(g)
                 arrays = tuple(jax.device_put(a, self._edges_sharding)
                                for a in sharr + hp)
-                step = self._sharded_step(arrays, tel, Ts, Te, full=False)
-            else:
-                step = make_wave_step_fn(tel, aux.v_cap, seg_pair=seg_pair,
-                                         seg_vert=aux.seg_vert,
-                                         use_kernel=self._use_kernel,
-                                         donate=donate,
-                                         resilience=self._resilience)
-            return WindowTEL(tel, seg_pair, aux.seg_vert, aux.v_cap, e,
-                             step)
+            step = self._sharded_step(arrays, tel, Ts, Te,
+                                      full=tel is self.tel)
+        else:
+            # pin the wave step per cache entry: both lowerings peel over
+            # this truncation's local tables, built once per (epoch, Ts,
+            # Te) and shared by every pipeline that peels this window (the
+            # program they feed is shared by the window's size class)
+            with TraceAnnotation("tcq.engine.window_tables", pool=pool,
+                                 edges=e):
+                tables = window_tables(host)
+            step = make_wave_step_fn(tel, aux.v_cap, tables=tables,
+                                     use_kernel=self._use_kernel,
+                                     donate=donate,
+                                     resilience=self._resilience)
+        return WindowTEL(tel, aux.v_cap, e, step)
 
     # ------------------------------------------------------------ pool seam
     def make_pool(self, lo: int, hi: int, *,
@@ -557,8 +515,7 @@ class TCQEngine:
             if wave == "auto":
                 wave = autotune_wave(wt.num_vertices, wt.window_edges,
                                      num_queries=num_queries, depth=depth)
-            pipe = WavePipeline(wt.tel, wt.num_vertices, wt.seg_pair,
-                                wt.seg_vert, wave, depth,
+            pipe = WavePipeline(wt.tel, wt.num_vertices, wave, depth,
                                 step_fn=wt.step_fn)
             return pipe, wt, wave
         from repro.core.distributed import ShardedWavePipeline

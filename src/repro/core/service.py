@@ -498,9 +498,12 @@ class TCQService:
         The pool runs inside a ``tcq.service.pump`` profiler span (args
         ``pool``, its sequence number, and ``members`` at formation); its
         ``pool_log`` record carries ``pool``, ``window_tel_miss`` (0/1),
-        ``step_compiles``, ``step_program_reuses`` and ``fallback`` (why
+        ``step_compiles``, ``step_program_reuses``, ``fallback`` (why
         a wanted fused kernel gave way to the composite: "smem_tables" |
-        "vmem_budget" | "build_error", else None).
+        "vmem_budget" | "build_error", else None), ``step_rows`` (the
+        [edge, pair, vertex] rows of the size class the pool's step peels
+        over, or None for a step without one) and ``peel_iters`` (the
+        pool's fixpoint iterations summed over its steps).
         """
         if poll is not None:
             poll(self)
@@ -609,6 +612,7 @@ class TCQService:
         self._inflight = []
         # drop window TELs / pair tables of epochs no ticket pins anymore
         self.engine.retire_epochs({t.epoch for t in self._pending})
+        rows = getattr(wt.step_fn, "rows", None)
         self.pool_log.append({
             "pool": pool, "epoch": epoch, "window": (pool_lo, pool_hi),
             "members": len(members), "wave": wave,
@@ -626,6 +630,8 @@ class TCQService:
             "window_tel_miss": int(self.engine._win_misses > misses),
             "step_compiles": pool_stats.step_compiles,
             "step_program_reuses": pool_stats.step_program_reuses,
+            "step_rows": None if rows is None else list(rows),
+            "peel_iters": pool_stats.peel_iters,
             "wall_s": done_s - t0,
         })
         if pool_stats.shard_occupancy is not None:

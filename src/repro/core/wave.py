@@ -11,20 +11,25 @@ cross-shard degree combine).
 
 The device step itself — :func:`wave_step` (peel + TTI + stats + uint32
 bitmask pack in one program) — lives here too, with two lowerings behind
-one dispatcher, :func:`make_wave_step_fn`:
+one dispatcher, :func:`make_wave_step_fn`.  Both peel in the window's own
+space: over the local edges, pairs, half-pairs and vertices of one table
+build (``core.graph.window_tables``), padded to the window's size class
+and passed as arguments, so each compiles once per (class, W, V):
 
   * **fused Pallas** (``kernels/wave_peel``): the entire fixpoint loop
     runs on-chip per W-tile — no [W, E] HBM round-trips between
     iterations (compiled on TPU, interpret mode for CPU gates);
-  * **XLA composite** (this module's ``peel_to_fixpoint`` chain): the
-    portable fallback, also used when a TEL's VMEM working set exceeds
-    the kernel budget.
+  * **XLA composite** (:func:`_wave_step_impl`): gathers, sorted segment
+    sums and a ``while_loop`` over the local tables; the portable
+    fallback, also used when a window's tables exceed the kernel's SMEM
+    or VMEM budget.
 
-Both lowerings are bit-identical (seeded fuzz gate in
-tests/test_kernels.py); ``engine.WavePipeline``, :func:`tcd_wave` and
-the distributed engine's single-shard block all route through the
-dispatcher, so one kernel serves the single-query, batched and sharded
-engines.
+Both lowerings are bit-identical to each other and to the graph-space
+numpy oracle step (seeded gates in tests/test_kernels.py and
+tests/test_composite_step.py).  ``engine.WavePipeline``,
+:func:`tcd_wave` and the distributed engine's single-shard block all
+route through the dispatcher.  :func:`peel_to_fixpoint` is the
+graph-space peel loop that :func:`tcd_wave`'s own jitted route runs.
 """
 
 from __future__ import annotations
@@ -40,7 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core.graph import DeviceTEL, TemporalGraph
+from repro.core.graph import (DeviceTEL, TemporalGraph, WindowTables,
+                              pow2_capacity, window_tables)
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 _I32_MIN = jnp.iinfo(jnp.int32).min
@@ -132,7 +138,7 @@ def peel_to_fixpoint(tel: DeviceTEL, alive: jnp.ndarray, ts, te, k, h,
                      *, num_vertices: int, seg_pair, seg_vert,
                      max_iters: int = 0):
     """Shared batched peel loop -> (alive, ea, iters); trace-time building
-    block for `tcd_wave` and the composite ``wave_step`` lowering.
+    block for `tcd_wave`'s graph-space route.
 
     k and h may be scalars (one threshold for the whole wave) or per-lane
     [Q] vectors — the multi-tenant scheduler packs cells from queries with
@@ -222,55 +228,227 @@ class StepResult(NamedTuple):
     iters: jnp.ndarray    # scalar int32 — shared fixpoint iterations
 
 
-def _wave_step_impl(tel: DeviceTEL, alive: jnp.ndarray, ts, te, k, h,
-                    *, num_vertices: int, seg_pair, seg_vert) -> StepResult:
-    alive, ea, iters = peel_to_fixpoint(
-        tel, alive, ts, te, k, h, num_vertices=num_vertices,
-        seg_pair=seg_pair, seg_vert=seg_vert)
+class ClassProgram:
+    """One jitted step that every window of a size class shares, and the
+    calls it has compiled for.
+
+    A call is keyed by what fixes its program besides the window's own
+    device arrays, which the class rows shape: the rows, any static
+    argument, and ``alive``'s shape (``ts``, ``te``, ``k``, ``h`` are
+    int32 [W] vectors, as the engine passes them).  JAX's own cache
+    holds one program per key."""
+
+    def __init__(self, fn, **jit_kw):
+        self.jitted = jax.jit(fn, **jit_kw)
+        self._ran = set()
+
+    def _keys(self) -> set:
+        """The keys whose programs JAX's cache still holds: emptied with
+        it, as ``jax.clear_caches()`` does."""
+        if self._ran and not self.jitted._cache_size():
+            self._ran.clear()
+        return self._ran
+
+    def warm(self, key) -> bool:
+        return key in self._keys()
+
+    def __call__(self, key, window_args, alive, ts, te, k, h, **kw):
+        ran = self._keys()       # before the call refills JAX's cache
+        out = self.jitted(*window_args, alive, ts, te, k, h, **kw)
+        if not isinstance(alive, jax.core.Tracer):   # not inlined
+            ran.add(key)
+        return out
+
+
+def pad_rows(a, rows: int, fill: int = 0) -> jnp.ndarray:
+    """A 1-D int table padded with ``fill`` to ``rows``, on the device."""
+    out = np.full(rows, fill, np.int32)
+    out[:a.size] = a
+    return jnp.asarray(out)
+
+
+def local_vertex_args(verts: np.ndarray, v_rows: int, num_vertices: int):
+    """The two vertex arguments of a window-space step: the local
+    vertices' global ids padded with the out-of-range ``num_vertices`` to
+    ``v_rows``, and each vertex's local row ([V] int32), ``v_rows`` (past
+    every row) for a vertex without a live edge."""
+    slot = np.full(num_vertices, v_rows, np.int32)
+    slot[verts] = np.arange(verts.size, dtype=np.int32)
+    return pad_rows(verts, v_rows, num_vertices), jnp.asarray(slot)
+
+
+def write_back(alive, slot, local, k, iters):
+    """A window-space peel's [W, V_rows] ``local`` lanes written back into
+    ``alive`` [W, V] through each vertex's local row ``slot``, and the
+    peel's iteration count.
+
+    A gather from the local rows, not a scatter into ``alive``: at
+    V = 194,085 the step with the scatter compiles in 13 s on a described
+    v5e, with this gather in 2.5 s.  Vertices without a live edge
+    (``slot`` past the rows) have degree 0 in every iteration: they
+    survive iff ``k`` ([W, 1]) <= 0 and change at most once, on the first
+    iteration, which the count folds in."""
+    outside = (slot >= local.shape[1])[None, :]
+    new = jnp.where(outside, alive & (k <= 0),
+                    local.at[:, slot].get(mode="fill", fill_value=False))
+    moved = jnp.any(alive & outside & (k > 0))
+    return new, jnp.maximum(iters, jnp.where(moved, 2, 1)).astype(jnp.int32)
+
+
+def _lane_gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """x[:, idx] for [W, N] lane rows; an index past N reads 0 (False)."""
+    return x.at[:, idx].get(mode="fill", fill_value=0)
+
+
+def _cumsum_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sums along the rows of [W, N] int32, N a power of
+    two: within blocks of 1,024, then over the blocks' totals.  One scan
+    over a whole row of 10^6 compiles for 20 s on the TPU; blocks compile
+    in under one."""
+    w, n = x.shape
+    if n <= 1024:
+        return jnp.cumsum(x, axis=1)
+    inner = jnp.cumsum(x.reshape(w, n // 1024, 1024), axis=2)
+    total = inner[:, :, -1]
+    before = _cumsum_rows(total) - total
+    return (inner + before[:, :, None]).reshape(w, n)
+
+
+def _segsum_sorted(x: jnp.ndarray, ends: jnp.ndarray) -> jnp.ndarray:
+    """Per-lane sums of [W, N] 0/1 values over consecutive segments,
+    ``ends`` [S] one past each segment's last row (non-decreasing) ->
+    [W, S] int32: the prefix sum differenced at the ends.  On the TPU
+    this is four times faster a peel iteration than a sorted
+    ``segment_sum``, whose scatter-add dominates it."""
+    c = jnp.pad(_cumsum_rows(x.astype(jnp.int32)), ((0, 0), (1, 0)))
+    at = c[:, ends]
+    return at - jnp.pad(at[:, :-1], ((0, 0), (1, 0)))
+
+
+def _wave_step_impl(tables, verts, slot, alive, ts, te, k, h
+                    ) -> StepResult:
+    """The XLA-composite step in the window's own space.
+
+    ``tables`` are the window's local tables padded to its size class
+    (``_composite_tables``): edge timestamps and local pair ids (grouped
+    by pair), one past each pair's last edge, each pair's two local ends,
+    one past each local vertex's last half-pair, and the half-pairs
+    sorted by local vertex (other end, pair); ``verts`` the local
+    vertices' global ids padded with the out-of-range ``alive.shape[1]``,
+    ``slot`` each vertex's local row (past the rows without a live edge).
+
+    The lanes' rows of the local vertices are gathered, peeled to the
+    fixpoint over local pairs and half-pairs, and scattered back.  A pair
+    counts for a lane iff it has at least h (>= 1) edges inside the lane's
+    window, counted once before the loop, and both its ends are alive, so
+    an alive vertex's degree is the sum over its half-pairs of
+    ``ok[pair] & alive[other]``.  Vertices without a live edge have degree
+    0: they survive iff k <= 0 and change at most once, on the first
+    iteration, which the iteration count folds in.
+    """
+    t, epair, pair_end, pair_u, pair_v, vertex_end, hp_other, hp_pair = \
+        tables
+    w, v = alive.shape
+
+    def lanes(x):
+        return jnp.broadcast_to(jnp.asarray(x, jnp.int32), (w,))[:, None]
+
+    ts, te, k, h = lanes(ts), lanes(te), lanes(k), lanes(h)
+    # padded vertex rows gather False and stay dead; padded edges carry
+    # t = int32 min (outside every window) and pair ids past the last
+    # row; padded pair and vertex rows are empty segments
+    cur = _lane_gather(alive, verts)                       # [W, V_rows]
+    win = (t[None, :] >= ts) & (t[None, :] <= te)          # [W, E_rows]
+    ok = _segsum_sorted(win, pair_end) >= h                # [W, P_rows]
+    ok_hp = _lane_gather(ok, hp_pair)                      # [W, 2 P_rows]
+
+    def body(state):
+        cur, _, it = state
+        deg = _segsum_sorted(ok_hp & _lane_gather(cur, hp_other),
+                             vertex_end)
+        new = cur & (deg >= k)
+        return new, jnp.any(new != cur), it + 1
+
+    cur, _, iters = lax.while_loop(lambda s: s[1], body,
+                                   (cur, jnp.bool_(True), jnp.int32(0)))
+    both = _lane_gather(cur, pair_u) & _lane_gather(cur, pair_v)
+    ea = win & _lane_gather(both, epair)                   # [W, E_rows]
     n_edges = jnp.sum(ea, axis=1, dtype=jnp.int32)
-    tti_lo = jnp.min(jnp.where(ea, tel.t[None, :], _I32_MAX), axis=1)
-    tti_hi = jnp.max(jnp.where(ea, tel.t[None, :], _I32_MIN), axis=1)
-    return StepResult(alive, _pack_u32(alive, num_vertices),
-                      tti_lo, tti_hi, n_edges, iters)
+    tti_lo = jnp.min(jnp.where(ea, t[None, :], _I32_MAX), axis=1)
+    tti_hi = jnp.max(jnp.where(ea, t[None, :], _I32_MIN), axis=1)
+    new, iters = write_back(alive, slot, cur, k, iters)
+    return StepResult(new, _pack_u32(new, v), tti_lo, tti_hi, n_edges,
+                      iters)
 
 
-#: XLA-composite device step: peel W lanes to the fixpoint + TTI + stats +
-#: bitmask pack in one jitted program.  ``ts``/``te``/``k``/``h`` are
-#: per-lane [W] vectors — every lane may carry a different query's window
-#: and thresholds.  ``alive`` is donated — the lane buffer is peeled in
-#: place and handed back as ``StepResult.alive``.
-wave_step = functools.partial(
-    jax.jit, static_argnames=("num_vertices", "seg_pair", "seg_vert"),
-    donate_argnums=(1,))(_wave_step_impl)
+#: XLA-composite device step: peel W lanes of one window to the fixpoint
+#: + TTI + stats + bitmask pack in one jitted program, shared by every
+#: window of a size class (``ClassProgram``, keyed by donation).
+#: ``ts``/``te``/``k``/``h`` are per-lane [W] vectors — every lane may
+#: carry a different query's window and thresholds.  The donating
+#: variant peels the ``alive`` lane buffer in place and hands it back as
+#: ``StepResult.alive``; the other is for callers that reuse their
+#: buffer across calls.
+_COMPOSITE = {donate: ClassProgram(_wave_step_impl,
+                                   donate_argnames=("alive",) if donate
+                                   else ())
+              for donate in (False, True)}
+wave_step = _COMPOSITE[True].jitted
 
-# non-donating twin for callers that reuse their alive buffer across calls
-# (tcd_wave, benches); same trace, separate jit cache
-_wave_step_nodonate = functools.partial(
-    jax.jit, static_argnames=("num_vertices", "seg_pair",
-                              "seg_vert"))(_wave_step_impl)
+
+def composite_rows(wt: WindowTables) -> tuple:
+    """The composite's size class: one power of two for the window's
+    edge, pair and vertex rows alike (pairs never outnumber edges).  One
+    dimension instead of three lets a few classes cover a deployment's
+    windows, so few programs compile (each takes seconds on the TPU);
+    the padding costs the composite little a row."""
+    rows = pow2_capacity(max(wt.n_edges, wt.n_vertices))
+    return rows, rows, rows
 
 
-def _make_xla_step(tel: DeviceTEL, num_vertices: int, *,
-                   seg_pair=None, seg_vert=None, donate: bool = False):
-    """The XLA-composite lowering as a ``make_wave_step_fn``-shaped
-    closure (also the degradation ladder's middle rung)."""
-    if seg_pair is None or seg_vert is None:
-        from repro.kernels.segdeg.ref import banded_segsum_ref
+def _composite_tables(wt: WindowTables, num_vertices: int):
+    """The composite's device arguments for one window: its class rows
+    and ``(tables, verts, slot)`` (see :func:`_wave_step_impl`),
+    padded with out-of-range ids and the int32-min timestamp."""
+    e_rows, p_rows, v_rows = rows = composite_rows(wt)
+    t, pair_end, pair_u, pair_v, vertex_end, hp_other, hp_pair = wt.tables
+    epair = np.repeat(np.arange(wt.n_pairs), np.diff(pair_end, prepend=0))
+    tables = (pad_rows(t, e_rows, _I32_MIN), pad_rows(epair, e_rows, p_rows),
+              pad_rows(pair_end, p_rows, wt.n_edges),
+              pad_rows(pair_u, p_rows, v_rows),
+              pad_rows(pair_v, p_rows, v_rows),
+              pad_rows(vertex_end, v_rows, 2 * wt.n_pairs),
+              pad_rows(hp_other, 2 * p_rows, v_rows),
+              pad_rows(hp_pair, 2 * p_rows, p_rows))
+    return rows, (tables, *local_vertex_args(wt.verts, v_rows, num_vertices))
 
-        if seg_pair is None:
-            seg_pair = functools.partial(banded_segsum_ref,
-                                         num_segments=tel.num_pairs)
-        if seg_vert is None:
-            seg_vert = functools.partial(banded_segsum_ref,
-                                         num_segments=num_vertices)
-    inner = wave_step if donate else _wave_step_nodonate
+
+def _make_xla_step(tables: WindowTables, num_vertices: int, *,
+                   donate: bool = False):
+    """The XLA-composite lowering over one window's tables as a
+    ``make_wave_step_fn``-shaped closure (also the degradation ladder's
+    middle rung).  Every window of a size class shares the compiled
+    program; ``step.program_warm(alive, ts, te, k, h)`` says whether a
+    call would reuse one this process has already run, and ``step.rows``
+    gives the class's edge, pair and vertex rows."""
+    rows, window_args = _composite_tables(tables, int(num_vertices))
+    program = _COMPOSITE[bool(donate)]
 
     def step(alive, ts, te, k, h):
-        return inner(tel, alive, ts, te, k, h, num_vertices=num_vertices,
-                     seg_pair=seg_pair, seg_vert=seg_vert)
+        return program((rows, tuple(alive.shape)), window_args, alive, ts,
+                       te, k, h)
 
+    def program_warm(alive, ts, te, k, h):
+        return program.warm((rows, tuple(alive.shape)))
+
+    step.program_warm = program_warm
+    # the shared program and this window's leading arguments to it
+    # (compile checks)
+    step.jitted = program.jitted
+    step.window_args = window_args
     step.backend = "xla"
     step.interpret = False
+    step.rows = rows
     return step
 
 
@@ -389,7 +567,7 @@ class DegradationLadder:
     """
 
     def __init__(self, tel: DeviceTEL, num_vertices: int, *,
-                 seg_pair=None, seg_vert=None,
+                 tables: Optional[WindowTables] = None,
                  use_kernel: bool = False,
                  interpret: Optional[bool] = None,
                  config: Optional[ResilienceConfig] = None):
@@ -403,6 +581,8 @@ class DegradationLadder:
         self._rng = np.random.default_rng(self.config.seed)
         if self.config.interpret is not None:
             interpret = self.config.interpret
+        if tables is None:
+            tables = window_tables(tel)
         rungs = []
         if use_kernel:
             from repro.kernels.wave_peel.ops import (DEFAULT_VMEM_BUDGET,
@@ -417,7 +597,8 @@ class DegradationLadder:
                                              interpret=interpret,
                                              donate=False,
                                              vmem_budget_bytes=budget,
-                                             on_refuse=refused.append)
+                                             on_refuse=refused.append,
+                                             tables=tables)
                 if fused is None:
                     self.fallback = refused[0]
                     self._log("pallas", self.fallback,
@@ -428,9 +609,7 @@ class DegradationLadder:
             except Exception as e:
                 self.fallback = "build_error"
                 self._log("pallas", "build_error", repr(e))
-        rungs.append(("xla", _make_xla_step(tel, num_vertices,
-                                            seg_pair=seg_pair,
-                                            seg_vert=seg_vert,
+        rungs.append(("xla", _make_xla_step(tables, num_vertices,
                                             donate=False)))
         oracle = make_oracle_step_fn(tel, num_vertices)
         self._truth = oracle        # tripwire ground truth stays unwrapped
@@ -452,10 +631,17 @@ class DegradationLadder:
     def interpret(self) -> bool:
         return bool(getattr(self.rungs[self.rung][1], "interpret", False))
 
+    @property
+    def rows(self):
+        """The current rung's class rows (edges, pairs, vertices), or None
+        on the oracle rung."""
+        return getattr(self.rungs[self.rung][1], "rows", None)
+
     def program_warm(self, alive, ts, te, k, h) -> bool:
         """Whether the current rung would reuse a compiled program: the
-        fused rung's size class already ran (its ``program_warm``); any
-        other rung, or a wrapped one, is taken to compile."""
+        fused or composite rung's size class already ran (its
+        ``program_warm``); the oracle rung, or a wrapped one, is taken to
+        compile."""
         warm = getattr(self.rungs[self.rung][1], "program_warm", None)
         return bool(warm is not None and warm(alive, ts, te, k, h))
 
@@ -508,7 +694,7 @@ class DegradationLadder:
 
 
 def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
-                      seg_pair=None, seg_vert=None,
+                      tables: Optional[WindowTables] = None,
                       use_kernel: Optional[bool] = None,
                       interpret: Optional[bool] = None,
                       donate: bool = False,
@@ -518,13 +704,16 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
     StepResult``, with ``.backend`` ("pallas" | "xla" | "oracle") and
     ``.interpret`` attributes.
 
-    use_kernel=True routes through the fused Pallas peel-to-fixpoint
-    kernel (interpret mode off-TPU unless ``interpret`` says otherwise);
-    False through the XLA composite; None (default) auto-dispatches —
-    compiled Pallas on TPU, XLA elsewhere.  A TEL whose index tables
-    exceed the kernel's SMEM budget, or whose VMEM working set exceeds
-    its VMEM budget, falls back to the composite, whose ``.fallback``
-    then names the budget ("smem_tables" | "vmem_budget").
+    Both lowerings peel over the TEL's ``window_tables`` (pass
+    ``tables`` where the caller has built them) and compile once per
+    size class.  use_kernel=True routes through the fused Pallas
+    peel-to-fixpoint kernel (interpret mode off-TPU unless ``interpret``
+    says otherwise); False through the XLA composite; None (default)
+    auto-dispatches — compiled Pallas on TPU, XLA elsewhere.  A TEL
+    whose index tables exceed the kernel's SMEM budget, or whose VMEM
+    working set exceeds its VMEM budget, falls back to the composite,
+    whose ``.fallback`` then names the budget ("smem_tables" |
+    "vmem_budget").
     ``donate=True`` donates the alive buffer (the pipeline's persistent
     lane slab); leave False when the caller reuses its buffer across
     calls.
@@ -544,13 +733,15 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
 
     if use_kernel is None:
         use_kernel = on_tpu()
+    if tables is None:
+        tables = window_tables(tel)
     if resilience is not None:
         if resilience.vmem_budget_bytes is None and \
                 vmem_budget_bytes is not None:
             resilience = dataclasses.replace(
                 resilience, vmem_budget_bytes=int(vmem_budget_bytes))
-        return DegradationLadder(tel, num_vertices, seg_pair=seg_pair,
-                                 seg_vert=seg_vert, use_kernel=use_kernel,
+        return DegradationLadder(tel, num_vertices, tables=tables,
+                                 use_kernel=use_kernel,
                                  interpret=interpret, config=resilience)
     if use_kernel:
         from repro.kernels.wave_peel.ops import (DEFAULT_VMEM_BUDGET,
@@ -562,11 +753,11 @@ def make_wave_step_fn(tel: DeviceTEL, num_vertices: int, *,
         fused = make_fused_wave_step(tel, num_vertices,
                                      interpret=interpret, donate=donate,
                                      vmem_budget_bytes=budget,
-                                     on_refuse=refused.append)
+                                     on_refuse=refused.append,
+                                     tables=tables)
         if fused is not None:
             return fused
-    step = _make_xla_step(tel, num_vertices, seg_pair=seg_pair,
-                          seg_vert=seg_vert, donate=donate)
+    step = _make_xla_step(tables, num_vertices, donate=donate)
     if use_kernel:
         step.fallback = refused[0]
     return step

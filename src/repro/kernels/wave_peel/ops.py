@@ -1,26 +1,33 @@
 """Dispatching wrapper + cost model for the fused wave-peel kernel.
 
-``make_fused_wave_step`` does the host-side analysis once per TEL (the
-window's live edges grouped by pair, its local pairs and vertices, the
-half-pair tables sorted by local vertex) and returns a
-``step(alive, ts, te, k, h) -> StepResult`` closure, or ``None`` when
-the tables exceed the kernel's SMEM capacity or its VMEM working set
-exceeds the budget (``fused_step_refusal`` names which) — callers
+Both device steps read one table build, ``core.graph.window_tables``: a
+(possibly padded) TEL compacted to its window once on the host — the
+live edges grouped by pair, the window's local pairs and vertices, and
+the half-pair tables sorted by local vertex.  The fused kernel here and
+the XLA composite (``core.wave``) each pad that one build to their own
+size class: the kernel's rows per table, to fit its VMEM budget; the
+composite's one power of two for all.
+
+``make_fused_wave_step`` returns a ``step(alive, ts, te, k, h) ->
+StepResult`` closure over them, or ``None`` when the tables exceed the
+kernel's SMEM capacity or its VMEM working set exceeds the budget
+(``fused_step_refusal`` names which) — callers
 (``core.wave.make_wave_step_fn``) then use the XLA composite.
 
 The compiled program is shared by every window of a *size class*
 (``fused_step_class``): the tables are padded to the class's power-of-two
 rows and passed as arguments, the live pair and vertex counts ride in
 SMEM as the kernel's loop bounds, and one module-level ``jax.jit``
-(``_step``) compiles once per (class, W, V).  A window that is new to
-the process but whose class has already run pays its host-side analysis
-and uploads, not a trace, lower and compile.
+(``_step``, held by a ``ClassProgram``) compiles once per (class, W, V).
+A window that is new to the process but whose class has already run pays
+its host-side analysis and uploads, not a trace, lower and compile.
 
 Vertices with no live edge in the TEL never reach the kernel: their
 degree is 0 in every iteration, so they survive iff ``k <= 0`` and can
 only change state on the first iteration.  The wrapper applies that rule
 directly and folds it into the iteration count, which keeps the step
-bit-identical to the composite on every ``StepResult`` field.
+bit-identical to ``core.wave.make_oracle_step_fn`` on every
+``StepResult`` field.
 
 ``fused_step_cost`` is the structural HBM/op model used by
 ``benchmarks/bench_wave.py``: the fused step's HBM bytes are
@@ -33,11 +40,12 @@ from __future__ import annotations
 
 from typing import Callable, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import pow2_capacity
+from repro.core.graph import WindowTables, pow2_capacity, window_tables
+from repro.core.wave import (ClassProgram, StepResult, _pack_u32,
+                             local_vertex_args, pad_rows, write_back)
 from repro.kernels.segdeg.ops import on_tpu
 from repro.kernels.wave_peel.kernel import LANES, wave_peel_pallas
 
@@ -137,56 +145,13 @@ def fused_step_class(num_edges: int, num_pairs: int, num_vertices: int,
     return max(num_edges, 1), max(num_pairs, 1), max(num_vertices, 1)
 
 
-def _window_tables(tel):
-    """Host-side compaction of a (possibly padded) TEL to its live edges.
-
-    Returns (verts, tables, n_edges, n_pairs): ``verts`` are the global
-    ids of the local vertices (sorted); ``tables`` the kernel's seven
-    1-D int32 tables at their live lengths (``n_edges``; ``n_pairs`` for
-    pair_end, pair_u, pair_v; ``verts.size`` for vertex_end; ``2 *
-    n_pairs`` for hp_other, hp_pair)."""
-    num_pairs = int(tel.pair_u.shape[0])
-    pair_id = np.asarray(tel.pair_id)
-    live = pair_id < num_pairs          # sentinel edges carry pair_id == P
-    pid = pair_id[live]
-    t = np.asarray(tel.t)[live]
-    src = np.asarray(tel.src)[live]
-    dst = np.asarray(tel.dst)[live]
-    if np.any(pid[1:] < pid[:-1]):
-        raise ValueError("TEL edges are not grouped by pair_id")
-    first = np.ones(pid.size, dtype=bool)
-    first[1:] = pid[1:] != pid[:-1]
-    starts = np.flatnonzero(first)
-    pair_end = np.append(starts[1:], pid.size)
-    verts = np.unique(np.concatenate([src[first], dst[first]]))
-    pu = np.searchsorted(verts, src[first])
-    pv = np.searchsorted(verts, dst[first])
-    n_pairs = int(starts.size)
-    hp_vert = np.concatenate([pu, pv])
-    order = np.argsort(hp_vert, kind="stable")
-    hp_other = np.concatenate([pv, pu])[order]
-    hp_pair = np.concatenate([np.arange(n_pairs)] * 2)[order]
-    vert_end = np.searchsorted(hp_vert[order], np.arange(verts.size),
-                               side="right")
-    tables = (t, pair_end, pu, pv, vert_end, hp_other, hp_pair)
-    return verts, tables, int(pid.size), n_pairs
-
-
-def _padded(a, rows: int, fill: int = 0):
-    out = np.full(rows, fill, np.int32)
-    out[:a.size] = a
-    return jnp.asarray(out)
-
-
-def _step(tables, counts, verts, isolated, alive, ts, te, k, h, *,
+def _step(tables, counts, verts, slot, alive, ts, te, k, h, *,
           interpret: bool):
     """One fused step over a window of some size class: ``tables`` padded
     to the class's rows, ``counts`` the live (pairs, vertices), ``verts``
     the local vertices' global ids padded with the out-of-range
-    ``alive.shape[1]``, ``isolated`` the [V] mask of vertices without a
-    live edge."""
-    from repro.core.wave import StepResult, _pack_u32
-
+    ``alive.shape[1]``, ``slot`` each vertex's local row (past the rows
+    without a live edge)."""
     w, v = alive.shape
     w_pad = _align(max(w, 1), LANES)
     # padding lanes carry the empty window (ts=0 > te=-1) and k=h=1
@@ -199,48 +164,32 @@ def _step(tables, counts, verts, isolated, alive, ts, te, k, h, *,
     prm = jnp.zeros((8, w_pad), jnp.int32)
     prm = prm.at[:4].set(jnp.stack([lanes(ts, 0), lanes(te, -1),
                                     lanes(k, 1), lanes(h, 1)]))
-    # padded vertex rows gather 0 (dead), stay 0 through the peel, and
-    # their scatter back is dropped
+    # padded vertex rows gather 0 (dead) and stay 0 through the peel; no
+    # vertex's slot points at them
     local = alive.at[:, verts].get(mode="fill", fill_value=False)
     slab = jnp.pad(local.T.astype(jnp.int32), ((0, 0), (0, w_pad - w)))
     out, st = wave_peel_pallas(tables, counts, prm, slab,
                                pair_rows=_align(tables[1].shape[0], 8),
                                interpret=interpret)
-    # vertices without live edges: degree 0, so they survive iff
-    # k <= 0 and change at most once (on the first iteration)
-    rest = alive & (k_l <= 0)[:, None]
-    new = rest.at[:, verts].set(out[:, :w].T > 0, mode="drop")
-    moved = jnp.any(alive & isolated[None, :] & (k_l > 0)[:, None])
-    iters = jnp.maximum(jnp.max(st[3]), jnp.where(moved, 2, 1))
+    new, iters = write_back(alive, slot, out[:, :w].T > 0, k_l[:, None],
+                            jnp.max(st[3]))
     return StepResult(new, _pack_u32(new, v), st[1, :w], st[2, :w],
-                      st[0, :w], iters.astype(jnp.int32))
+                      st[0, :w], iters)
 
 
-# keyed by donation; JAX's own cache holds one program per (class, W, V)
-_JITTED = {donate: jax.jit(_step, static_argnames=("interpret",),
-                           donate_argnames=("alive",) if donate else ())
-           for donate in (False, True)}
-# per donation variant, the (table rows, interpret, alive shape) of
-# every program it has run.  The other arguments are fixed by the step:
-# its own device arrays, which the rows shape, and ts, te, k, h as int32
-# [W] vectors, as the engine passes them.
-_RAN = {False: set(), True: set()}
-
-
-def _ran(donate: bool) -> set:
-    """The programs that the donation variant's JAX cache still holds:
-    emptied with it, as ``jax.clear_caches()`` does."""
-    ran = _RAN[donate]
-    if ran and not _JITTED[donate]._cache_size():
-        ran.clear()
-    return ran
+# keyed by donation
+_PROGRAMS = {donate: ClassProgram(_step, static_argnames=("interpret",),
+                                  donate_argnames=("alive",) if donate
+                                  else ())
+             for donate in (False, True)}
 
 
 def make_fused_wave_step(tel, num_vertices: int, *,
                          interpret: Optional[bool] = None,
                          donate: bool = False,
                          vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
-                         on_refuse: Optional[Callable[[str], None]] = None):
+                         on_refuse: Optional[Callable[[str], None]] = None,
+                         tables: Optional[WindowTables] = None):
     """Build the fused Pallas step for one (capacity-shaped) DeviceTEL.
 
     Returns ``step(alive [W, V] bool, ts, te, k, h) -> StepResult`` (the
@@ -258,11 +207,13 @@ def make_fused_wave_step(tel, num_vertices: int, *,
     h)`` says whether a call with those arguments would reuse a program
     this process has already run, judged on the class and ``alive``'s
     shape (``ts``, ``te``, ``k``, ``h`` are int32 [W] vectors).
+    ``tables`` is the TEL's ``window_tables``, where the caller has
+    built it already.
     """
     interp = (not on_tpu()) if interpret is None else bool(interpret)
     v = int(num_vertices)
     budget = int(vmem_budget_bytes)
-    verts, live, n_e, n_p = _window_tables(tel)
+    verts, live, n_e, n_p = window_tables(tel) if tables is None else tables
     n_v = int(verts.size)
     refusal = None if interp else fused_step_refusal(n_e, n_p, n_v, budget)
     if refusal is not None:
@@ -271,35 +222,29 @@ def make_fused_wave_step(tel, num_vertices: int, *,
         return None
     e_rows, p_rows, v_rows = fused_step_class(n_e, n_p, n_v, v, budget)
     rows = (e_rows, p_rows, p_rows, p_rows, v_rows, 2 * p_rows, 2 * p_rows)
-    tables = tuple(_padded(a, r) for a, r in zip(live, rows))
+    padded = tuple(pad_rows(a, r) for a, r in zip(live, rows))
     counts = jnp.asarray(np.array([n_p, n_v], np.int32))
-    verts_d = _padded(verts, _align(v_rows, 8), fill=v)
-    isolated = np.ones(v, dtype=bool)
-    isolated[verts] = False
-    isolated_d = jnp.asarray(isolated)
-    donate = bool(donate)
-    jitted = _JITTED[donate]
+    verts_d, slot_d = local_vertex_args(verts, _align(v_rows, 8), v)
+    program = _PROGRAMS[bool(donate)]
+    window_args = (padded, counts, verts_d, slot_d)
 
     def step(alive, ts, te, k, h):
-        ran = _ran(donate)       # before the call refills JAX's cache
-        out = jitted(tables, counts, verts_d, isolated_d, alive, ts, te, k,
-                     h, interpret=interp)
-        if not isinstance(alive, jax.core.Tracer):   # not inlined
-            ran.add((rows, interp, tuple(alive.shape)))
-        return out
+        return program((rows, interp, tuple(alive.shape)), window_args,
+                       alive, ts, te, k, h, interpret=interp)
 
     def program_warm(alive, ts, te, k, h):
-        return (rows, interp, tuple(alive.shape)) in _ran(donate)
+        return program.warm((rows, interp, tuple(alive.shape)))
 
     step.program_warm = program_warm
     # the shared program and this window's leading arguments to it
     # (compile checks)
-    step.jitted = jitted
-    step.window_args = (tables, counts, verts_d, isolated_d)
+    step.jitted = program.jitted
+    step.window_args = window_args
     step.backend = "pallas"
     step.interpret = interp
+    step.rows = (e_rows, p_rows, v_rows)
     step.local_counts = (n_e, n_p, n_v)
     # operand census for perf_lower's structural assert: nothing
     # [W, E]-shaped ever crosses HBM on this path
-    step.operand_shapes = [tuple(x.shape) for x in tables]
+    step.operand_shapes = [tuple(x.shape) for x in padded]
     return step

@@ -1,0 +1,7 @@
+"""``device_idle_share`` in the YouTube-growth cell, whose windows run the XLA
+composite over the window's own rows and whose few long requests a window
+report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("device_idle_share")

@@ -1,0 +1,7 @@
+"""``peel_step_roofline`` in the YouTube-growth cell, whose windows run the XLA
+composite over the window's own rows and whose few long requests a window
+report their latency as a mean."""
+
+from tcqbench.registry import Registry
+
+read = Registry().reader("peel_step_roofline")

@@ -362,7 +362,7 @@ def test_fused_step_class_over_smem_budget_takes_exact_sizes(monkeypatch):
     from repro.core.wave import make_wave_step_fn
 
     tel = _class_window("random", 11)
-    verts, _, n_e, n_p = ops._window_tables(tel)
+    verts, _, n_e, n_p = ops.window_tables(tel)
     exact = ops.fused_step_smem_bytes(n_e, n_p, verts.size)
     assert ops.fused_step_smem_bytes(128, 128, CLASS_V) > exact
     monkeypatch.setattr(ops, "SMEM_TABLE_BUDGET", exact)
@@ -383,7 +383,7 @@ def test_fused_step_class_over_vmem_budget_buckets_pairs_and_vertices():
     from repro.core.wave import make_wave_step_fn
 
     tel = _class_window("matching", 13)      # at most 120 vertices
-    verts, _, n_e, n_p = ops._window_tables(tel)
+    verts, _, n_e, n_p = ops.window_tables(tel)
     p_cap = pow2_capacity(n_p)
     v_cap = min(pow2_capacity(verts.size), CLASS_V)
     budget = ops.fused_step_vmem_bytes(p_cap, v_cap)

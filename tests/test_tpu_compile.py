@@ -5,7 +5,8 @@ for a topology that is described, not present, and refuses what the chip
 would refuse (Mosaic lowering errors, SMEM/VMEM overflow, unpartitionable
 programs).  Interpret-mode tests cannot see any of that.  These tests
 compile the fused wave-peel step at the chip smoke's widths, the banded
-segsum kernel, the XLA composite step, and the sharded steps on a 2x2 and
+segsum kernel, the window-space XLA composite step (at the smoke's and
+the YouTube-growth cell's size class), and the sharded steps on a 2x2 and
 a 4x1 mesh of the described devices.  Nothing runs, so nothing here says
 anything about results or times.
 
@@ -116,23 +117,37 @@ def test_banded_segsum_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_composite_step_compiles(one_chip):
-    from repro.core.graph import DeviceTEL
-    from repro.core.wave import _wave_step_nodonate
-    from repro.kernels.segdeg.ref import banded_segsum_ref
+V_YOUTUBE = 3_223_589     # YouTube-growth graph: vertex width
 
-    _, tel = _window_tel()
-    tel_sds = DeviceTEL(*(_sds(x.shape, x.dtype, one_chip) for x in tel))
-    w = 64
-    lane = _sds((w,), jnp.int32, one_chip)
-    compiled = _wave_step_nodonate.lower(
-        tel_sds, _sds((w, V_SMOKE), jnp.bool_, one_chip),
-        lane, lane, lane, lane, num_vertices=V_SMOKE,
-        seg_pair=functools.partial(banded_segsum_ref,
-                                   num_segments=tel.num_pairs),
-        seg_vert=functools.partial(banded_segsum_ref,
-                                   num_segments=V_SMOKE)).compile()
-    assert compiled.memory_analysis() is not None
+
+@pytest.mark.parametrize("case", ["smoke", "youtube"])
+def test_composite_step_compiles(one_chip, case):
+    """The window-space XLA composite compiles for the described chip at
+    a window's size class: the smoke window's own, and the YouTube-growth
+    cell's widest (524,288 edge, pair and vertex rows, 4 lanes over its
+    3,223,589 vertices)."""
+    from repro.core.graph import window_tables
+    from repro.core.wave import _COMPOSITE, _make_xla_step
+
+    if case == "smoke":
+        _, tel = _window_tel()
+        step = _make_xla_step(window_tables(tel), V_SMOKE)
+        (e_rows, p_rows, v_rows), v, w = step.rows, V_SMOKE, 64
+        assert step.jitted is _COMPOSITE[False].jitted
+    else:
+        e_rows = p_rows = v_rows = 524_288
+        v, w = V_YOUTUBE, 4
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    tables = tuple(i32((n,)) for n in (e_rows, e_rows, p_rows, p_rows,
+                                       p_rows, v_rows, 2 * p_rows,
+                                       2 * p_rows))
+    lane = i32((w,))
+    compiled = _COMPOSITE[True].jitted.lower(
+        tables, i32((v_rows,)), i32((v,)),
+        _sds((w, v), jnp.bool_, one_chip), lane, lane, lane, lane).compile()
+    mem = compiled.memory_analysis()
+    assert mem is not None
+    assert mem.temp_size_in_bytes < (4 << 30)
 
 
 @pytest.mark.parametrize("combine", ["psum", "rs_ag"])

@@ -14,7 +14,8 @@ from jax.profiler import ProfileData
 from repro.core import ResilienceConfig, TCQService, TemporalGraph
 
 SPANS = ("tcq.service.pump", "tcq.service.finalize",
-         "tcq.engine.window_tel", "tcq.engine.step_compile",
+         "tcq.engine.window_tel", "tcq.engine.window_tables",
+         "tcq.engine.step_compile",
          "tcq.pipeline.run_pool", "tcq.pipeline.assemble",
          "tcq.pipeline.dispatch", "tcq.pipeline.retire",
          "tcq.pipeline.sync")
@@ -49,7 +50,8 @@ def inside(inner, outer):
 
 @pytest.fixture(scope="module")
 def traced_service(tmp_path_factory):
-    """Two disjoint windows (two pools), then a repeat of the first
+    """Two disjoint windows of one size class (two pools: the second
+    reuses the first's composite program), then a repeat of the first
     (a window-TEL hit: no build, no first call), served under the
     profiler."""
     svc = TCQService(small_graph(), use_kernel=False, cache=False)
@@ -76,6 +78,8 @@ def test_every_span_appears_and_nests(traced_service):
                            "tcq.pipeline.dispatch"),
                           ("tcq.pipeline.run_pool", "tcq.service.pump"),
                           ("tcq.engine.window_tel", "tcq.service.pump"),
+                          ("tcq.engine.window_tables",
+                           "tcq.engine.window_tel"),
                           ("tcq.service.finalize", "tcq.service.pump")]:
         for s in by[child]:
             assert any(inside(s, p) and p[3]["pool"] == s[3]["pool"]
@@ -94,10 +98,15 @@ def test_span_counts_match_the_counters(traced_service):
     misses = svc.stats["window_tel"]["misses"]
     assert misses == 2
     assert count["tcq.engine.window_tel"] == misses
-    assert count["tcq.engine.step_compile"] == misses
-    assert sum(p["step_compiles"] for p in svc.pool_log) == misses
+    assert count["tcq.engine.window_tables"] == misses
+    assert count["tcq.engine.step_compile"] == 1
+    assert sum(p["step_compiles"] for p in svc.pool_log) == 1
     assert [p["window_tel_miss"] for p in svc.pool_log] == [1, 1, 0]
-    assert [p["step_compiles"] for p in svc.pool_log] == [1, 1, 0]
+    assert [p["step_compiles"] for p in svc.pool_log] == [1, 0, 0]
+    assert [p["step_program_reuses"] for p in svc.pool_log] == [0, 1, 0]
+    edges = [s[3]["edges"] for s in spans
+             if s[0] == "tcq.engine.window_tables"]
+    assert edges == [p["window_edges"] for p in svc.pool_log[:2]]
     assert all(p["fallback"] is None for p in svc.pool_log)
 
 
@@ -133,10 +142,12 @@ def test_each_fallback_reason_is_named(monkeypatch, reason):
     elif reason == "vmem_budget":
         monkeypatch.setattr(ops, "DEFAULT_VMEM_BUDGET", 1)
     else:
-        def broken(tel):
-            raise RuntimeError("table build failed")
+        def broken(*args):
+            raise RuntimeError("class build failed")
 
-        monkeypatch.setattr(ops, "_window_tables", broken)
+        # the window tables are shared with the composite; the fused
+        # step's own build starts at its size class
+        monkeypatch.setattr(ops, "fused_step_class", broken)
         kw["resilience"] = True     # the ladder turns the error into a rung
     g = small_graph()
     svc = TCQService(g, use_kernel=True, cache=False, **kw)
